@@ -57,8 +57,8 @@ class ModelConfig:
     # modality stub: number of precomputed prefix embeddings (VLM patches /
     # audio conditioning frames) supplied by input_specs()
     prefix_len: int = 0
-    # numerics
-    param_dtype: str = "float32"
+    # numerics: parameters are created in float32 (training masters);
+    # serving casts its weights to compute_dtype (launch.serve)
     compute_dtype: str = "bfloat16"
     # remat policy for the layer scan: 'none' | 'dots' | 'full'
     remat: str = "full"

@@ -1,6 +1,6 @@
-"""qwen3-4b — Qwen3 dense 4B-class. [hf:Qwen/Qwen3-8B; hf]
+"""qwen3-4b — Qwen3 dense 4B. [hf:Qwen/Qwen3-4B config.json]
 36L d_model=2560 32H (GQA kv=8, head_dim=128) d_ff=9728 vocab=151936,
-qk-norm enabled."""
+qk-norm enabled, rope_theta=1e6, tied input/output embeddings."""
 
 from .base import ModelConfig
 
@@ -16,5 +16,6 @@ CONFIG = ModelConfig(
     vocab_size=151936,
     qk_norm=True,
     rope_theta=1_000_000.0,
+    tie_embeddings=True,
     activation="swiglu",
 )

@@ -20,7 +20,7 @@ knob reports those fallbacks the same way ``simulate_batch``'s does.
 Numerical contract (asserted by ``tests/test_graph_sim.py``): every
 engine operation reproduces the lockstep band's float64 arithmetic —
 same operand order, same host-precomputed cost prefix sums — under
-``jax.experimental.enable_x64``.  Worker-axis reductions are unrolled
+``jax.enable_x64``.  Worker-axis reductions are unrolled
 at trace time in NumPy's exact ``pairwise_sum`` association order (see
 :func:`_numpy_order_sum` — XLA's row reduce may SIMD-reassociate even a
 4-element sum), and multiply-add sites are guarded against XLA's FMA
@@ -40,7 +40,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .batch_sim import (
     BatchConfig,
@@ -605,7 +604,7 @@ def simulate_batch_graph(
     for gl in glanes:
         groups.setdefault((gl.spec.technique, gl.cfg.p), []).append(gl)
     if groups:
-        with enable_x64():
+        with jax.enable_x64(True):
             for (_, p), group in groups.items():
                 _run_group(group, p, results)
 

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -143,21 +144,13 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
         out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, nq * block_q, hd), q.dtype),
         scratch_shapes=[
-            pl.MemorySpace.ANY if False else _vmem((block_q,), jnp.float32),
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
     return out[:, :s, :]
-
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover - fallback for interpret-only envs
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore
 
 
 # ---------------------------------------------------------------------------
@@ -213,11 +206,13 @@ def _flash_sched_kernel(bi_ref, qi_ref, kj_ref, fst_ref, lst_ref, lim_ref,
     @pl.when(lst_ref[g] == 1)
     def _finalize():
         # rows with every column masked (ragged padding) keep m == NEG_INF;
-        # zero them instead of emitting the uniform-softmax garbage
-        alive = m_scr[...] > NEG_INF * 0.5
+        # zero them instead of emitting the uniform-softmax garbage.  The
+        # mask is compared after the (bq,) -> (bq, 1) reshape: Mosaic
+        # cannot reshape a 1-D boolean vector
+        alive = m_scr[...][:, None] > NEG_INF * 0.5
         l = jnp.maximum(l_scr[...], 1e-30)
         out = acc_scr[...] / l[:, None]
-        o_ref[0] = jnp.where(alive[:, None], out, 0.0).astype(o_ref.dtype)
+        o_ref[0] = jnp.where(alive, out, 0.0).astype(o_ref.dtype)
 
 
 def flash_kv_group_costs(bh: int, s: int, block_q: int, block_k: int, *,
@@ -322,8 +317,6 @@ def flash_attention_sched_bhsd(q, k, v, *,
     Output is bit-identical for every ``schedule`` — the technique only
     permutes whole q-block groups; each group's kv steps stay ascending.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     bh, s, hd = q.shape
     scale = 1.0 / math.sqrt(hd)
     block_q = min(block_q, max(s, 8))
@@ -362,9 +355,9 @@ def flash_attention_sched_bhsd(q, k, v, *,
             (1, block_q, hd),
             lambda i, bi, qi, kj, fst, lst, lim: (bi[i], qi[i], 0)),
         scratch_shapes=[
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
         ],
     )
     kernel = functools.partial(

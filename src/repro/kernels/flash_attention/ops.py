@@ -2,7 +2,7 @@
 
 `flash_attention` accepts model-layout tensors (b, s, h, hd) with separate
 kv-head counts (GQA/MQA) and handles head broadcast, flattening, padding,
-and the interpret-mode switch (CPU validation vs TPU execution).
+and passes `interpret` through (True only for CPU validation).
 
 Passing ``schedule=`` routes through the schedule-aware kernel
 (`flash_attention_sched_bhsd`): the KV-tile grid order is produced by the
@@ -21,10 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .flash_attention import flash_attention_bhsd, flash_attention_sched_bhsd
-
-
-def _is_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 def _broadcast_flatten(q, k, v):
@@ -58,7 +54,7 @@ def _flash_attention_dense(q, k, v, *, causal, window, block_q, block_k,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool | None = None,
+                    interpret: bool = False,
                     schedule: Union[str, object, None] = None,
                     kv_lens: Optional[Sequence[int]] = None,
                     sched_p: int = 8, recorder=None):
@@ -69,8 +65,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     (ragged decode lanes) — columns past a lane's length are masked.
     ``recorder`` (LoopRecorder) collects the plan's kernel telemetry.
     """
-    if interpret is None:
-        interpret = not _is_tpu()
     if schedule is None:
         if kv_lens is not None:
             raise ValueError("kv_lens requires schedule= (the DLS-planned "

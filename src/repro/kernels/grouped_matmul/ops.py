@@ -26,10 +26,6 @@ import numpy as np
 from .grouped_matmul import grouped_matmul_tiles
 
 
-def _is_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "interpret"))
 def _grouped_matmul_core(xe, weights, tile_order, *, block_rows: int,
@@ -54,7 +50,7 @@ def _grouped_matmul_core(xe, weights, tile_order, *, block_rows: int,
 
 
 def grouped_matmul(xe, weights, tile_order=None, *, block_rows: int = 128,
-                   interpret: bool | None = None,
+                   interpret: bool = False,
                    schedule: Union[str, object, None] = None,
                    expert_rows: Optional[Sequence[int]] = None,
                    sched_p: int = 8, recorder=None):
@@ -70,8 +66,6 @@ def grouped_matmul(xe, weights, tile_order=None, *, block_rows: int = 128,
     receives the plan's kernel telemetry.  Mutually exclusive with an
     explicit ``tile_order``.
     """
-    if interpret is None:
-        interpret = not _is_tpu()
     if schedule is not None:
         if tile_order is not None:
             raise ValueError("pass either tile_order or schedule, not both")

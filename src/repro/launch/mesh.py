@@ -13,6 +13,13 @@ import numpy as np
 from ..sharding import DEFAULT_RULES, ShardingRules
 
 
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all ``Auto``: the sharding rules place arrays
+    with ``with_sharding_constraint``, which Explicit axes refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False,
                          dm_shape: tuple[int, int] | None = None):
     """16x16 = 256 chips/pod; multi-pod adds a leading pod=2 axis.
@@ -22,13 +29,13 @@ def make_production_mesh(*, multi_pod: bool = False,
     assert d * m == 256, (d, m)
     shape = (2, d, m) if multi_pod else (d, m)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally (smoke/integration tests)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def replica_submeshes(mesh, num_replicas: int, axis: str = "data"):

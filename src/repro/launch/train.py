@@ -16,6 +16,7 @@ from ..configs import ARCHS, get_arch, smoke_config
 from ..data.pipeline import DataConfig
 from ..optim.adamw import OptimizerConfig
 from ..train.trainer import Trainer, TrainerConfig
+from .compile_cache import use_compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--full", action="store_true",
                     help="train the full assigned config (TPU-scale)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if not args.full:

@@ -167,9 +167,17 @@ def _group_split(cfg) -> tuple[int, tuple[str, ...], tuple[str, ...]]:
 
 
 def _stack_init(init_fn, keys):
-    outs = [init_fn(k) for k in keys]
-    params = jax.tree.map(lambda *a: jnp.stack(a), *[p for p, _ in outs])
-    axes = jax.tree.map(lambda ax: Ax("stack", *ax.names), outs[0][1])
+    """Stacked (G, ...) params of G layers, one per key.  vmap traces the
+    layer once (the values equal a per-key loop), so a jitted init
+    compiles one layer instead of G."""
+    captured = {}
+
+    def params_only(key):
+        p, captured["axes"] = init_fn(key)
+        return p
+
+    params = jax.vmap(params_only)(keys)
+    axes = jax.tree.map(lambda ax: Ax("stack", *ax.names), captured["axes"])
     return params, axes
 
 

@@ -16,6 +16,7 @@ batch lanes here, to replicas in the scheduler simulation.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -29,7 +30,35 @@ from ..core.schedule import resolve
 from ..models import decode_step, init_decode_state
 from .scheduler import Request, RequestScheduler
 
-__all__ = ["DecodeEngine", "EngineStats"]
+__all__ = ["DecodeEngine", "EngineStats", "decode_program"]
+
+
+def decode_program(cfg):
+    """The engine's jitted decode step ``(params, state, tokens) ->
+    (logits, state)``.  The state is donated, so the step updates the
+    caches in place instead of holding an input and an output copy."""
+    return jax.jit(lambda p, st, t: decode_step(p, cfg, st, t),
+                   donate_argnums=1)
+
+
+# jitted so the stacked caches are written once, not built per layer and
+# then copied into the stack (twice the cache, briefly, at full width)
+_init_state = jax.jit(init_decode_state,
+                      static_argnames=("cfg", "batch", "max_len"))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _splice_lane(state, fresh, s):
+    """``state`` with lane ``s`` replaced by the single-lane ``fresh``:
+    per-lane pos -> 0 (which masks the stale KV entries) and recurrent
+    states zeroed.  The state is donated, so the caches are updated in
+    place instead of copied."""
+    grp = jax.tree.map(lambda a, f: a.at[:, s].set(f[:, 0]),
+                       state.group_caches, fresh.group_caches)
+    rem = jax.tree.map(lambda a, f: a.at[s].set(f[0]),
+                       state.rem_caches, fresh.rem_caches)
+    return state._replace(group_caches=grp, rem_caches=rem,
+                          pos=state.pos.at[s].set(0))
 
 
 @dataclasses.dataclass
@@ -52,9 +81,13 @@ class DecodeEngine:
                  technique="fac2", greedy: bool = True,
                  temperature: float = 1.0, seed: int = 0,
                  kernel_schedule="fac2", kernel_p: int = 8,
-                 kv_block: int = 16, shed_slo: Optional[float] = None):
+                 kv_block: int = 16, shed_slo: Optional[float] = None,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
-        self.params = params
+        # params, decode state and per-step tokens all live on one device
+        # (default: the first), so replicas can each hold their own chip
+        self.device = jax.devices()[0] if device is None else device
+        self.params = jax.device_put(params, self.device)
         self.slots = slots
         self.max_len = max_len
         # deadline-aware shedding (serve/resilience.py's admission
@@ -73,9 +106,12 @@ class DecodeEngine:
         self.kernel_p = kernel_p
         self.kv_block = kv_block
         self.kernel_recorder = LoopRecorder()
-        self._step = jax.jit(
-            lambda p, st, t: decode_step(p, cfg, st, t))
-        self.state = init_decode_state(cfg, slots, max_len=max_len)
+        self._step = decode_program(cfg)
+        with jax.default_device(self.device):
+            self.state = _init_state(cfg=cfg, batch=slots, max_len=max_len)
+            self._fresh = _init_state(cfg=cfg, batch=1, max_len=max_len)
+        # logits of the latest decode step (slots, 1, vocab), on device
+        self.last_logits: Optional[jax.Array] = None
         self.greedy = greedy
         self.temperature = temperature
         self._rng = jax.random.key(seed)
@@ -87,7 +123,6 @@ class DecodeEngine:
         self._outputs: dict[int, list[int]] = {}
         self._tokens = np.zeros((slots, 1), np.int32)
         self._used = [False] * slots
-        self._fresh = init_decode_state(cfg, 1, max_len=max_len)
         # decode steps spent on the slot's current admission chunk — the
         # throughput measurement fed back to the DLS scheduler so adaptive
         # techniques (AF/AWF*) see real per-slot service times
@@ -103,18 +138,6 @@ class DecodeEngine:
         self.plan_calls = 0          # admissions that planned
         self.plan_time_s = 0.0       # host time spent planning
         self.plan_cache_hits = 0     # plans served from the memo cache
-
-    def _reset_lane(self, s: int) -> None:
-        """Splice a fresh single-lane state into lane s: per-lane pos -> 0
-        (which masks the stale KV entries) and recurrent states zeroed."""
-        fresh = self._fresh
-        grp = jax.tree.map(lambda a, f: a.at[:, s].set(f[:, 0]),
-                           self.state.group_caches, fresh.group_caches)
-        rem = jax.tree.map(lambda a, f: a.at[s].set(f[0]),
-                           self.state.rem_caches, fresh.rem_caches)
-        self.state = self.state._replace(
-            group_caches=grp, rem_caches=rem,
-            pos=self.state.pos.at[s].set(0))
 
     # -- public ----------------------------------------------------------------
     def submit(self, req: Request, prompt: Optional[list[int]] = None):
@@ -183,6 +206,12 @@ class DecodeEngine:
 
     def output(self, rid: int) -> list[int]:
         return self._outputs.get(rid, [])
+
+    @property
+    def lane_requests(self) -> list[Optional[int]]:
+        """Request id decoding on each lane (None for an idle lane);
+        row ``i`` of ``last_logits`` belongs to lane ``i``."""
+        return [None if r is None else r.rid for r in self._active]
 
     @property
     def kernel_records(self):
@@ -279,7 +308,7 @@ class DecodeEngine:
                 if self._queue[s]:
                     req = self._queue[s].pop(0)
                     if self._used[s]:
-                        self._reset_lane(s)
+                        self.state = _splice_lane(self.state, self._fresh, s)
                     self._used[s] = True
                     self._active[s] = req
                     self._active_mask[s] = True
@@ -296,7 +325,8 @@ class DecodeEngine:
     def _advance(self, stats: EngineStats):
         self._rng, sub = jax.random.split(self._rng)
         logits, self.state = self._step(
-            self.params, self.state, jnp.asarray(self._tokens))
+            self.params, self.state, jax.device_put(self._tokens, self.device))
+        self.last_logits = logits
         if self.greedy:
             nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         else:

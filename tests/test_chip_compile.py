@@ -1,0 +1,127 @@
+"""Compile the main path for a described TPU v5e chip (no chip attached).
+
+The TPU compiler refuses what interpret mode accepts: Mosaic layouts a
+kernel cannot use, and programs that do not fit the device's memory.
+These tests compile the three Pallas kernels at real widths and the
+full-width qwen3-4b decode step, exactly as ``DecodeEngine`` jits it.
+A compile is not a run: nothing here says anything about results or
+times.
+
+The topology is described inside a module fixture, never at import:
+only one process may hold the TPU library, and every test worker
+imports this file.
+"""
+
+import math
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+# what the compiler lets one v5e program use ("Used ... of 15.75G hbm")
+V5E_PROGRAM_BYTES = 15.75e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _is_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_bhsd)
+
+    q = _spec((32, 2048, 128), jnp.bfloat16, one_chip)
+    compiled = jax.jit(flash_attention_bhsd).lower(q, q, q).compile()
+    assert _is_kernel(compiled)
+
+
+def test_flash_attention_sched_ragged_compiles(one_chip):
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_sched_bhsd)
+
+    bh, s = 32, 2048
+    kv_lens = np.linspace(100, s, bh).astype(np.int64)
+    q = _spec((bh, s, 128), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention_sched_bhsd(
+        q, k, v, kv_lens=kv_lens)).lower(q, q, q).compile()
+    assert _is_kernel(compiled)
+
+
+@pytest.mark.parametrize("e,d,f", [(128, 2048, 768), (32, 1024, 512)])
+def test_grouped_matmul_compiles(one_chip, e, d, f):
+    from repro.kernels.grouped_matmul.grouped_matmul import (
+        grouped_matmul_tiles)
+
+    t = 2 * e
+    compiled = jax.jit(grouped_matmul_tiles).lower(
+        _spec((t, 128, d), jnp.bfloat16, one_chip),
+        _spec((e, d, f), jnp.bfloat16, one_chip),
+        _spec((t,), jnp.int32, one_chip)).compile()
+    assert _is_kernel(compiled)
+
+
+def test_qwen3_4b_decode_step_fits_one_chip(one_chip):
+    """bf16 weights, 8 lanes x max_len 2048, state donated: the program
+    fits, and the donated state is aliased instead of double-buffered."""
+    from repro.configs import get_arch
+    from repro.launch.serve import serving_init
+    from repro.models import init_decode_state
+    from repro.serve.engine import decode_program
+
+    cfg = get_arch("qwen3-4b")
+
+    def place(tree):
+        return jax.tree.map(
+            lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = place(jax.eval_shape(serving_init(cfg), 0))
+    assert {leaf.dtype for leaf in jax.tree.leaves(params)} == {
+        jnp.dtype(jnp.bfloat16)}
+    state = place(init_decode_state(cfg, 8, max_len=2048, spec=True))
+    tokens = _spec((8, 1), jnp.int32, one_chip)
+    mem = decode_program(cfg).lower(params, state, tokens).compile(
+    ).memory_analysis()
+
+    state_bytes = sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                      for leaf in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < V5E_PROGRAM_BYTES, used
+
+
+def test_qwen3_4b_serving_init_builds_bf16_on_chip(one_chip):
+    """The serving init writes the bf16 weights directly: its scratch is a
+    small fraction of the float32 tree it never holds."""
+    from repro.configs import get_arch
+    from repro.launch.serve import serving_init
+
+    cfg = get_arch("qwen3-4b")
+    seed = _spec((), jnp.int32, one_chip)
+    mem = serving_init(cfg).lower(seed).compile().memory_analysis()
+    f32_tree_bytes = 2 * mem.output_size_in_bytes
+    assert mem.temp_size_in_bytes < 0.05 * f32_tree_bytes
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < (
+        V5E_PROGRAM_BYTES)

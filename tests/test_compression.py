@@ -45,16 +45,15 @@ def test_error_feedback_preserves_sum():
 
 def test_compressed_psum_matches_exact():
     n_dev = len(jax.devices())
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    mesh = jax.make_mesh((n_dev,), ("pod",))
+    mesh = jax.make_mesh((n_dev,), ("pod",), axis_types=(AxisType.Auto,))
     x = jnp.asarray(np.random.default_rng(2).normal(
         0, 1, (n_dev, 512)).astype(np.float32))
 
     @jax.jit
     def run(x):
-        return shard_map(
+        return jax.shard_map(
             lambda v: compressed_psum(v[0], "pod"),
             mesh=mesh, in_specs=P("pod"), out_specs=P(),
         )(x)

@@ -224,3 +224,15 @@ def test_engine_disabled_lane_shrinks_shed_budget(model):
         stats = eng.run()
         shed_counts.append(stats.shed)
     assert shed_counts[1] > shed_counts[0]
+
+
+def test_engine_donates_decode_state(model):
+    """The jitted step consumes the previous state in place: the cache is
+    never held twice, which is what lets a full-width cache fit a chip."""
+    cfg, params = model
+    eng = DecodeEngine(cfg, params, slots=2, max_len=64)
+    before = eng.state
+    eng.submit(_req(0))
+    eng.run()
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(before))
+    assert eng.last_logits.shape == (2, 1, cfg.padded_vocab)

@@ -57,7 +57,8 @@ def test_parse_collectives_skips_trivial_groups():
 @pytest.fixture(scope="module")
 def mesh():
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_logical_to_spec_divisibility_fallback(mesh):
@@ -198,3 +199,44 @@ def test_kv8_decode_close_to_bf16():
     rel = float(jnp.max(jnp.abs(dec - full)) /
                 (jnp.max(jnp.abs(full)) + 1e-9))
     assert rel < 5e-2, rel  # int8 cache: small, bounded degradation
+
+
+# --- serving launcher ---------------------------------------------------------
+
+
+def _serve_args(*extra):
+    from repro.launch.serve import parse_args
+
+    return parse_args(["--arch", "qwen3-4b", "--requests", "6",
+                       "--slots", "2", "--max-len", "32", *extra])
+
+
+def test_serving_build_casts_weights_and_draws_requests():
+    from repro.launch.serve import build
+
+    sv = build(_serve_args("--prompt-len", "3", "5",
+                           "--new-tokens", "2", "2"))
+    assert {leaf.dtype for leaf in jax.tree.leaves(sv.params)} == {
+        jnp.dtype(sv.cfg.compute_dtype)}
+    assert [r.rid for r in sv.requests] == list(range(6))
+    assert all(3 <= r.prompt_len <= 5 for r in sv.requests)
+    assert all(r.max_new_tokens == 2 for r in sv.requests)
+
+
+def test_serve_cluster_matches_one_engine():
+    """The --replicas path serves every request once, with the same greedy
+    tokens as one engine, and reports where each replica's arrays live."""
+    from repro.launch.serve import build, make_engine, run_cluster
+
+    args = _serve_args("--replicas", "3")
+    sv = build(args)
+    eng = make_engine(sv, args)
+    for r in sv.requests:
+        eng.submit(r)
+    assert eng.run().completed == len(sv.requests)
+    out = run_cluster(sv, args)
+    assert out["completed"] == len(sv.requests)
+    assert out["outputs"] == {r.rid: eng.output(r.rid) for r in sv.requests}
+    n = len(jax.devices())
+    assert out["replica_devices"] == [[jax.devices()[i % n].id]
+                                      for i in range(3)]

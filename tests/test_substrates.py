@@ -131,7 +131,8 @@ def test_checkpoint_elastic_reshard(tmp_path):
     store = CheckpointStore(str(tmp_path), keep=1, async_write=False)
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     store.save(1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P("data"))}
     out, _ = store.restore(1, tree, shardings=sh)
     np.testing.assert_array_equal(np.asarray(out["w"]),
