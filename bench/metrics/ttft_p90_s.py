@@ -1,0 +1,9 @@
+"""ttft_p90_s: 90th percentile of due time to first output token over
+every attempted request (host clock; a request with no token by the
+drain cap counts as infinitely late)."""
+
+from bench import stats
+
+
+def read(view):
+    return stats.percentile(stats.ttft(view.run), 90)
