@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Optional
 
 import jax
@@ -27,8 +26,9 @@ import numpy as np
 from ..core.jax_sched import plan_tiles_cached
 from ..core.metrics import LoopRecorder
 from ..core.schedule import resolve
-from ..models import decode_step, init_decode_state
+from .. import models
 from .scheduler import Request, RequestScheduler
+from .spans import Recorder
 
 __all__ = ["DecodeEngine", "EngineStats", "decode_program"]
 
@@ -36,14 +36,18 @@ __all__ = ["DecodeEngine", "EngineStats", "decode_program"]
 def decode_program(cfg):
     """The engine's jitted decode step ``(params, state, tokens) ->
     (logits, state)``.  The state is donated, so the step updates the
-    caches in place instead of holding an input and an output copy."""
-    return jax.jit(lambda p, st, t: decode_step(p, cfg, st, t),
-                   donate_argnums=1)
+    caches in place instead of holding an input and an output copy.  A
+    profiler trace names the program ``jit_decode_step`` whatever the
+    configuration."""
+    def decode_step(params, state, tokens):
+        return models.decode_step(params, cfg, state, tokens)
+
+    return jax.jit(decode_step, donate_argnums=1)
 
 
 # jitted so the stacked caches are written once, not built per layer and
 # then copied into the stack (twice the cache, briefly, at full width)
-_init_state = jax.jit(init_decode_state,
+_init_state = jax.jit(models.init_decode_state,
                       static_argnames=("cfg", "batch", "max_len"))
 
 
@@ -66,7 +70,7 @@ class EngineStats:
     completed: int = 0
     steps: int = 0
     tokens: int = 0
-    wall_s: float = 0.0
+    wall_s: float = 0.0          # the run() call's ``engine.run`` span
     # requests shed at admission by the deadline-aware policy
     # (DecodeEngine(shed_slo=...)); 0 when shedding is disabled
     shed: int = 0
@@ -135,9 +139,12 @@ class DecodeEngine:
         self._active_mask = np.zeros(slots, bool)
         self._disabled = [False] * slots  # lanes out of service (faults)
         self._need_refill = True
-        self.plan_calls = 0          # admissions that planned
-        self.plan_time_s = 0.0       # host time spent planning
-        self.plan_cache_hits = 0     # plans served from the memo cache
+        # the operator's record of the serving loop (serve/spans.py): a
+        # span per phase of each step, one per request from submit to its
+        # lane, and the plan-cache hits under counts["plan_cache_hits"]
+        self.spans = Recorder()
+        self._submitted: dict[int, float] = {}   # rid -> submit time
+        self._steps_run = 0      # decode steps so far: the step spans' ident
 
     # -- public ----------------------------------------------------------------
     def submit(self, req: Request, prompt: Optional[list[int]] = None):
@@ -148,6 +155,7 @@ class DecodeEngine:
                                                         self.max_len // 2))
             ).tolist()
         req.prompt_tokens = prompt  # type: ignore[attr-defined]
+        self._submitted[req.rid] = self.spans.now()
         self.sched.submit(req)
 
     def set_slot_enabled(self, s: int, enabled: bool) -> None:
@@ -187,21 +195,21 @@ class DecodeEngine:
 
     def run(self, max_steps: int = 10_000) -> EngineStats:
         stats = EngineStats()
-        t0 = time.time()
-        self._shed(stats)
-        self._refill()
-        while self._active_mask.any() or self.sched.backlog:
-            if stats.steps >= max_steps:
-                break
-            if not self._active_mask.any() and all(self._disabled):
-                break  # every lane out of service: the backlog must wait
-            self._advance(stats)
-            if self._need_refill:
-                # only when a slot retired: steady-state decode steps
-                # skip the admission scan (and any re-planning) entirely
-                self._shed(stats)
-                self._refill()
-        stats.wall_s = time.time() - t0
+        with self.spans.span("engine.run", self._steps_run) as call:
+            self._shed(stats)
+            self._refill()
+            while self._active_mask.any() or self.sched.backlog:
+                if stats.steps >= max_steps:
+                    break
+                if not self._active_mask.any() and all(self._disabled):
+                    break  # every lane out of service: the backlog must wait
+                self._advance(stats)
+                if self._need_refill:
+                    # only when a slot retired: steady-state decode steps
+                    # skip the admission scan (and any re-planning) entirely
+                    self._shed(stats)
+                    self._refill()
+        stats.wall_s = call.t1 - call.t0
         return stats
 
     def output(self, rid: int) -> list[int]:
@@ -241,12 +249,10 @@ class DecodeEngine:
         costs = np.maximum(np.ceil(live / self.kv_block), 1.0)
         from ..core.jax_sched import kernel_plan_cache_stats
         hits0 = kernel_plan_cache_stats()["hits"]
-        t0 = time.perf_counter()
         plan = plan_tiles_cached(costs, p=self.kernel_p,
                                  technique=self.kernel_spec)
-        self.plan_time_s += time.perf_counter() - t0
-        self.plan_calls += 1
-        self.plan_cache_hits += kernel_plan_cache_stats()["hits"] - hits0
+        self.spans.counts["plan_cache_hits"] += \
+            kernel_plan_cache_stats()["hits"] - hits0
         self.kernel_recorder.add(plan.to_record(
             "decode_kv",
             instance=self.kernel_recorder.next_instance("decode_kv")))
@@ -284,75 +290,94 @@ class DecodeEngine:
         dropped = self.sched.drop(lambda r: r.rid in over)
         for req in dropped:
             self.shed_rids.append(req.rid)
+            self._submitted.pop(req.rid, None)
         if stats is not None:
             stats.shed += len(dropped)
         return len(dropped)
 
     def _refill(self):
-        admitted = False
-        for s in range(self.slots):
-            if self._disabled[s]:
-                continue
-            if self._active[s] is None:
-                if not self._queue[s]:
-                    if self._chunk_open[s]:
-                        self.sched.complete(s, elapsed=float(
-                            max(self._chunk_steps[s], 1)))
-                        self._chunk_open[s] = False
-                    chunk = self.sched.pull(s)
-                    if chunk:
-                        self._queue[s] = chunk
-                        self._chunk_open[s] = True
-                        self._chunk_steps[s] = 0
-                        admitted = True
-                if self._queue[s]:
-                    req = self._queue[s].pop(0)
-                    if self._used[s]:
-                        self.state = _splice_lane(self.state, self._fresh, s)
-                    self._used[s] = True
-                    self._active[s] = req
-                    self._active_mask[s] = True
-                    self._prompt_left[s] = list(req.prompt_tokens)
-                    self._emitted[s] = 0
-                    self._outputs[req.rid] = []
-                    self._tokens[s, 0] = self._prompt_left[s].pop(0)
-        self._need_refill = False
-        if admitted:
-            # after activation, so the plan sees the admitted lanes too
-            # (a single-slot engine would otherwise never record)
-            self._record_kernel_plan()
+        spans, k = self.spans, self._steps_run
+        with spans.span("engine.refill", k):
+            admitted = False
+            for s in range(self.slots):
+                if self._disabled[s]:
+                    continue
+                if self._active[s] is None:
+                    if not self._queue[s]:
+                        if self._chunk_open[s]:
+                            self.sched.complete(s, elapsed=float(
+                                max(self._chunk_steps[s], 1)))
+                            self._chunk_open[s] = False
+                        chunk = self.sched.pull(s)
+                        if chunk:
+                            self._queue[s] = chunk
+                            self._chunk_open[s] = True
+                            self._chunk_steps[s] = 0
+                            admitted = True
+                    if self._queue[s]:
+                        req = self._queue[s].pop(0)
+                        if self._used[s]:
+                            with spans.span("engine.splice", k):
+                                self.state = _splice_lane(self.state,
+                                                          self._fresh, s)
+                        self._used[s] = True
+                        self._active[s] = req
+                        self._active_mask[s] = True
+                        self._prompt_left[s] = list(req.prompt_tokens)
+                        self._emitted[s] = 0
+                        self._outputs[req.rid] = []
+                        self._tokens[s, 0] = self._prompt_left[s].pop(0)
+                        # a request requeued by a lane fault waited once
+                        t0 = self._submitted.pop(req.rid, None)
+                        if t0 is not None:
+                            spans.record("request.queued", t0, spans.now(),
+                                         req.rid)
+            self._need_refill = False
+            if admitted:
+                # after activation, so the plan sees the admitted lanes too
+                # (a single-slot engine would otherwise never record)
+                with spans.span("engine.plan", k):
+                    self._record_kernel_plan()
 
     def _advance(self, stats: EngineStats):
-        self._rng, sub = jax.random.split(self._rng)
-        logits, self.state = self._step(
-            self.params, self.state, jax.device_put(self._tokens, self.device))
+        spans, k = self.spans, self._steps_run
+        with spans.span("engine.upload", k):
+            tokens = jax.device_put(self._tokens, self.device)
+        with spans.span("engine.dispatch", k):
+            logits, self.state = self._step(self.params, self.state, tokens)
         self.last_logits = logits
-        if self.greedy:
-            nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
-        else:
-            nxt = np.asarray(jax.random.categorical(
-                sub, logits[:, -1, :] / self.temperature, axis=-1))
-        stats.steps += 1
-        for s in range(self.slots):
-            req = self._active[s]
-            if req is None:
-                self._tokens[s, 0] = 0
-                continue
-            self._chunk_steps[s] += 1
-            if self._prompt_left[s]:
-                # still prefilling: feed the next prompt token
-                self._tokens[s, 0] = self._prompt_left[s].pop(0)
-                continue
-            tok = int(nxt[s])
-            self._outputs[req.rid].append(tok)
-            self._emitted[s] += 1
-            stats.tokens += 1
-            if self._emitted[s] >= min(req.max_new_tokens,
-                                       self.max_len // 2):
-                stats.completed += 1
-                self._active[s] = None
-                self._active_mask[s] = False
-                self._need_refill = True
-                self._tokens[s, 0] = 0
+        with spans.span("engine.sample", k):
+            self._rng, sub = jax.random.split(self._rng)
+            if self.greedy:
+                nxt = jnp.argmax(logits[:, -1, :], axis=-1)
             else:
-                self._tokens[s, 0] = tok
+                nxt = jax.random.categorical(
+                    sub, logits[:, -1, :] / self.temperature, axis=-1)
+        with spans.span("engine.sync", k):
+            nxt = np.asarray(nxt)
+        self._steps_run += 1
+        stats.steps += 1
+        with spans.span("engine.lanes", k):
+            for s in range(self.slots):
+                req = self._active[s]
+                if req is None:
+                    self._tokens[s, 0] = 0
+                    continue
+                self._chunk_steps[s] += 1
+                if self._prompt_left[s]:
+                    # still prefilling: feed the next prompt token
+                    self._tokens[s, 0] = self._prompt_left[s].pop(0)
+                    continue
+                tok = int(nxt[s])
+                self._outputs[req.rid].append(tok)
+                self._emitted[s] += 1
+                stats.tokens += 1
+                if self._emitted[s] >= min(req.max_new_tokens,
+                                           self.max_len // 2):
+                    stats.completed += 1
+                    self._active[s] = None
+                    self._active_mask[s] = False
+                    self._need_refill = True
+                    self._tokens[s, 0] = 0
+                else:
+                    self._tokens[s, 0] = tok

@@ -98,9 +98,9 @@ def test_engine_reports_chunk_service_times(model):
 
 def test_engine_plans_only_on_admission_change(model):
     """The serving hot path must not re-plan per decode step: planning
-    happens once per admission (plan_calls == kernel records), repeated
-    lane-length signatures come out of the memo cache, and steady-state
-    decode steps skip the admission scan entirely."""
+    happens once per admission (one ``engine.plan`` span per kernel
+    record), repeated lane-length signatures come out of the memo cache,
+    and steady-state decode steps skip the admission scan entirely."""
     from repro.core.jax_sched import kernel_plan_cache_clear
 
     kernel_plan_cache_clear()
@@ -112,9 +112,10 @@ def test_engine_plans_only_on_admission_change(model):
         eng.submit(_req(i, prompt_len=4, new=4))
     stats = eng.run()
     assert stats.completed == 8
-    assert eng.plan_calls == len(eng.kernel_records)
-    assert eng.plan_calls < stats.steps  # not every decode step
-    assert eng.plan_cache_hits > 0      # repeated signatures reused
+    plans = [s for s in eng.spans.ring if s[0] == "engine.plan"]
+    assert len(plans) == len(eng.kernel_records)
+    assert len(plans) < stats.steps     # not every decode step
+    assert eng.spans.counts["plan_cache_hits"] > 0  # signatures reused
     # telemetry still records one plan per admission, in order
     assert [r.instance for r in eng.kernel_records] == \
         list(range(len(eng.kernel_records)))
