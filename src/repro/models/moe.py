@@ -12,8 +12,9 @@ Balancing mechanisms:
      loss-free balancing via self-scheduling weights.
 
 Dispatch implementations:
-  * 'dense'  — every expert runs on every token, gate-combined; scanned
-    over expert chunks so memory stays bounded.  Clean HLO but inflates
+  * 'dense'  — every expert runs on every token, gate-combined; in one
+    pass at decode sizes, scanned over expert chunks at prefill and
+    training sizes so memory stays bounded.  Clean HLO but inflates
     compute by E/top_k — the baseline whose waste the roofline's
     MODEL_FLOPS/HLO_FLOPS ratio exposes.
   * 'ragged' — sort-based dispatch: tokens sorted by expert id, gathered
@@ -92,15 +93,39 @@ def _capacity(cfg, tokens: int) -> int:
     return max(8, -(-c // 8) * 8)  # round up to multiple of 8
 
 
-def moe_dense(params, cfg, x, expert_chunk: int = 16):
+# Largest (tokens, experts, expert d_ff) transient, in bytes, for which
+# every expert runs in one pass. Decode (32 tokens x 128 x 768 in bf16,
+# 6.3e6 B) sits far below it; training and prefill at thousands of tokens
+# sit far above it and keep the chunked scan.
+WHOLE_PASS_BYTES = 32 * 2**20
+EXPERT_CHUNK = 16
+
+
+def expert_chunk_for(tokens: int, num_experts: int, d_ff: int,
+                     itemsize: int) -> int:
+    """Experts per step of `moe_dense`: all of them when the transient is
+    small, else chunks of `EXPERT_CHUNK`."""
+    if tokens * num_experts * d_ff * itemsize <= WHOLE_PASS_BYTES:
+        return num_experts
+    return min(EXPERT_CHUNK, num_experts)
+
+
+def moe_dense(params, cfg, x, expert_chunk: int | None = None):
     """Baseline: run every expert on every token, combine by gates.
 
-    Scanned over expert chunks of size `expert_chunk` so the (b, s, chunk,
-    ff) transient stays bounded at 32k-prefill scale."""
+    When the (b, s, experts, ff) transient is small (`expert_chunk_for`),
+    every expert runs in one pass, and the expert stacks are read where
+    they lie: inside a layer scan they stay slices of the stacked
+    parameters, never copied out. At prefill and training scale the
+    experts are scanned in chunks of `EXPERT_CHUNK` so the transient stays
+    bounded. `expert_chunk` forces a chunk size."""
     b, s, d = x.shape
     e = cfg.moe
     idx, gate, aux, load = _route(params, cfg, x)
     dt = x.dtype
+    if expert_chunk is None:
+        expert_chunk = expert_chunk_for(b * s, e.num_experts, e.d_ff,
+                                        jnp.dtype(dt).itemsize)
     ec = min(expert_chunk, e.num_experts)
     assert e.num_experts % ec == 0
     nchunk = e.num_experts // ec
@@ -109,20 +134,9 @@ def moe_dense(params, cfg, x, expert_chunk: int = 16):
     bidx = jnp.arange(b)[:, None, None]
     sidx = jnp.arange(s)[None, :, None]
     wfull = wfull.at[bidx, sidx, idx].add(gate)
-
-    wi = params["wi"].reshape(nchunk, ec, d, -1)
-    wo = params["wo"].reshape(nchunk, ec, -1, d)
     wg = params.get("wg")
-    if wg is not None:
-        wg = wg.reshape(nchunk, ec, d, -1)
-    wchunk = wfull.reshape(b, s, nchunk, ec).transpose(2, 0, 1, 3)
 
-    def body(acc, inp):
-        if wg is not None:
-            wi_c, wo_c, wg_c, w_c = inp
-        else:
-            wi_c, wo_c, w_c = inp
-            wg_c = None
+    def experts(wi_c, wo_c, wg_c, w_c):
         h_lin = jnp.einsum("bsd,edf->bsef", x, wi_c.astype(dt))
         if wg_c is not None:
             h = activate(jnp.einsum("bsd,edf->bsef", x, wg_c.astype(dt)),
@@ -130,10 +144,22 @@ def moe_dense(params, cfg, x, expert_chunk: int = 16):
         else:
             h = activate(h_lin, None, cfg.activation)
         y = jnp.einsum("bsef,efd->bsed", h, wo_c.astype(dt))
-        acc = acc + jnp.einsum("bsed,bse->bsd", y, w_c.astype(dt))
-        return acc, None
+        return jnp.einsum("bsed,bse->bsd", y, w_c.astype(dt))
 
-    xs = (wi, wo, wg, wchunk) if wg is not None else (wi, wo, wchunk)
+    if nchunk == 1:
+        y = experts(params["wi"], params["wo"], wg, wfull)
+        return shard_as(y, "batch", "seq", "embed_act"), aux, load
+
+    wi = params["wi"].reshape(nchunk, ec, d, -1)
+    wo = params["wo"].reshape(nchunk, ec, -1, d)
+    if wg is not None:
+        wg = wg.reshape(nchunk, ec, d, -1)
+    wchunk = wfull.reshape(b, s, nchunk, ec).transpose(2, 0, 1, 3)
+
+    def body(acc, inp):
+        return acc + experts(*inp), None
+
+    xs = (wi, wo, wg, wchunk)  # wg None: an empty leaf, sliced to None
     # checkpoint the chunk body: the (b, s, chunk, ff) transients are
     # recomputed in backward instead of saved across all E/chunk steps
     y, _ = jax.lax.scan(jax.checkpoint(body), jnp.zeros((b, s, d), dt), xs)

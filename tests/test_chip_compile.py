@@ -2,8 +2,9 @@
 
 The TPU compiler refuses what interpret mode accepts: Mosaic layouts a
 kernel cannot use, and programs that do not fit the device's memory.
-These tests compile the three Pallas kernels at real widths and the
-full-width qwen3-4b decode step, exactly as ``DecodeEngine`` jits it.
+These tests compile the three Pallas kernels at real widths, and the
+full-width qwen3-4b and 8-layer qwen3-moe-30b-a3b decode steps exactly
+as ``DecodeEngine`` jits them.
 A compile is not a run: nothing here says anything about results or
 times.
 
@@ -12,7 +13,9 @@ only one process may hold the TPU library, and every test worker
 imports this file.
 """
 
+import dataclasses
 import math
+import re
 
 import numpy as np
 import pytest
@@ -110,6 +113,46 @@ def test_qwen3_4b_decode_step_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used < V5E_PROGRAM_BYTES, used
+
+
+def test_qwen3_moe_decode_step_reads_experts_in_place(one_chip):
+    """qwen3-moe-30b-a3b, 8 layers, bf16, 32 lanes x 2048: the dense MoE
+    runs every expert in one pass at decode, so no layer's expert stack
+    is copied out of the stacked weights into a buffer of its own (three
+    such copies a layer made 1.34e9 B of temp)."""
+    from repro.configs import get_arch
+    from repro.launch.serve import serving_init
+    from repro.models import init_decode_state
+    from repro.serve.engine import decode_program
+
+    cfg = dataclasses.replace(get_arch("qwen3-moe-30b-a3b"), num_layers=8)
+
+    def place(tree):
+        return jax.tree.map(
+            lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = place(jax.eval_shape(serving_init(cfg), 0))
+    state = place(init_decode_state(cfg, 32, max_len=2048, spec=True))
+    tokens = _spec((32, 1), jnp.int32, one_chip)
+    compiled = decode_program(cfg).lower(params, state, tokens).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+    # instructions of the entry and the loop bodies: every computation
+    # that no fusion calls
+    text = compiled.as_text()
+    fused = set(re.findall(r"fusion\(.*?calls=(%[\w.-]+)", text))
+    top, in_fusion = [], False
+    for line in text.splitlines():
+        if re.match(r"^\S.*\{$", line):
+            words = line.split()
+            in_fusion = words[words[0] == "ENTRY"] in fused
+        elif not in_fusion:
+            top.append(line.strip())
+    expert_stacks = ("bf16[1,128,2048,768]", "bf16[1,128,768,2048]",
+                     "bf16[128,2048,768]", "bf16[128,768,2048]")
+    copies = [ln for ln in top if " = " in ln and ln.split(" = ", 1)[
+        1].startswith(expert_stacks)]
+    assert not copies, copies
 
 
 def test_qwen3_4b_serving_init_builds_bf16_on_chip(one_chip):

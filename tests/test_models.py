@@ -92,8 +92,10 @@ def test_decode_matches_forward(name):
     assert rel < 2e-2, rel
 
 
-def test_moe_dense_vs_ragged_dispatch():
-    """The two dispatch paths are equivalent when capacity drops nothing."""
+@pytest.mark.parametrize("expert_chunk", [None, 2])
+def test_moe_dense_vs_ragged_dispatch(expert_chunk):
+    """The two dispatch paths are equivalent when capacity drops nothing,
+    with the dense path in one pass (None) and chunk-scanned (2)."""
     from repro.models.moe import init_moe, moe_dense, moe_ragged
 
     cfg = smoke_config(ARCHS["granite-moe-1b-a400m"])
@@ -103,11 +105,43 @@ def test_moe_dense_vs_ragged_dispatch():
     params, _ = init_moe(jax.random.key(0), cfg)
     x = jax.random.normal(jax.random.key(1), (2, 16, cfg.d_model),
                           jnp.float32)
-    yd, aux_d, load_d = moe_dense(params, cfg, x, expert_chunk=2)
+    yd, aux_d, load_d = moe_dense(params, cfg, x, expert_chunk=expert_chunk)
     yr, aux_r, load_r = moe_ragged(params, cfg, x)
     np.testing.assert_allclose(np.asarray(yd), np.asarray(yr),
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(load_d), np.asarray(load_r))
+
+
+def test_moe_dense_one_pass_matches_chunked_scan():
+    """All experts in one pass and the chunked scan compute the same sum."""
+    from repro.models.moe import init_moe, moe_dense
+
+    cfg = smoke_config(ARCHS["qwen3-moe-30b-a3b"])
+    cfg = dataclasses.replace(cfg, compute_dtype="float32")
+    params, _ = init_moe(jax.random.key(0), cfg)
+    x = jax.random.normal(jax.random.key(1), (4, 1, cfg.d_model),
+                          jnp.float32)
+    whole = jax.jit(lambda p, x: moe_dense(p, cfg, x))
+    chunked = jax.jit(lambda p, x: moe_dense(p, cfg, x, expert_chunk=1))
+    y1, aux1, load1 = whole(params, x)
+    y2, aux2, load2 = chunked(params, x)
+    assert "while" not in whole.lower(params, x).as_text()
+    assert "while" in chunked.lower(params, x).as_text()
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+                               atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(load1), np.asarray(load2))
+    assert float(aux1) == float(aux2)
+
+
+@pytest.mark.parametrize("tokens,expect", [(32 * 1, 128), (8 * 4096, 16)])
+def test_moe_expert_chunk_rule(tokens, expect):
+    """qwen3-moe widths in bf16: decode at 32 lanes runs all 128 experts
+    at once; a training batch of 8 x 4096 tokens scans chunks of 16."""
+    from repro.models.moe import expert_chunk_for
+
+    e = ARCHS["qwen3-moe-30b-a3b"].moe
+    assert (e.num_experts, e.d_ff) == (128, 768)
+    assert expert_chunk_for(tokens, e.num_experts, e.d_ff, 2) == expect
 
 
 def test_moe_capacity_drops_tokens():
