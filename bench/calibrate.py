@@ -4,9 +4,10 @@
 
 On the accelerator, in one process, runs the cell once per seed as a
 benchmark run does (its own load, its sample of finished requests), and
-reads beside the program's numbers the fp8 control's: the same
-reference in float8 e4m3, at the same prompts and served tokens, taking
-the gap of the token it puts first.  The control's numbers are judged by
+reads beside the program's numbers the control's: the family module's
+reference in the precision below the configuration's (``gaps(...,
+control=True)``), at the same prompts and served tokens, taking the gap
+of the token it puts first.  The control's numbers are judged by
 the cell's own limits, so each line says whether the program and the
 control come out correct.  ``--no-listeners`` leaves out the compile and
 garbage-collection listeners, to see whether a host stall comes without

@@ -2,10 +2,12 @@
 
 A configuration file holds the source's ``config.json`` numbers under
 the source's own keys, the keys it changed (``reduced``), the sizes set
-by hand (``assumed``), the deployment it stands for, and the engine's
-``slots`` and ``max_len``.  The model is the program's registered
-architecture (``arch``): every source number it uses must equal the
-file's, and only the keys listed in ``reduced`` are replaced.
+by hand (``assumed``), the deployment it stands for, the engine's
+``slots`` and ``max_len``, and its family module (``reference``).  The
+model is the program's registered architecture (``arch``): every source
+number it uses, by the family's map of source keys to the program's
+fields, must equal the file's, and only the keys listed in ``reduced``
+are replaced.  All that is particular to a family is in its module.
 """
 
 from __future__ import annotations
@@ -14,26 +16,10 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable
 
 ROOT = Path(__file__).resolve().parents[1]
-
-# source key -> ModelConfig field ("moe." for MoEConfig fields)
-SOURCE_KEYS = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "tie_word_embeddings": "tie_embeddings",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "num_experts": "moe.num_experts",
-    "num_experts_per_tok": "moe.top_k",
-    "moe_intermediate_size": "moe.d_ff",
-}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +31,7 @@ class Cell:
     end_to_end: tuple     # BENCHMARK.json metric entries of this cell
     per_layer: tuple
     root: Path
+    family: ModuleType    # bench/reference/<config's "reference">.py
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -65,68 +52,85 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         traffic=json.loads(traffic_file.read_text()),
         end_to_end=tuple(m for m in bench["end_to_end"] if _applies(m, name)),
         per_layer=tuple(m for m in bench["per_layer"] if _applies(m, name)),
-        root=root)
+        root=root, family=family(config, root))
+
+
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str, root: Path = ROOT) -> Callable[[Any], Any]:
     """``read(run)`` of ``bench/metrics/<name>.py``."""
     path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"bench_metric_{name.replace('.', '_')}").read
 
 
-def _used_keys(config: dict) -> list[str]:
-    keys = [k for k in SOURCE_KEYS if k in config]
-    if "num_experts" in config:
-        # every layer is sparse, so the dense FFN width is never built
-        _require(config.get("decoder_sparse_step", 1) == 1
-                 and not config.get("mlp_only_layers"),
-                 "the program builds every layer sparse")
-        keys.remove("intermediate_size")
-    return keys
+# family modules by their source, so that one loaded from another root
+# keeps its compiled programs
+_FAMILIES: dict[bytes, ModuleType] = {}
 
 
-def _require(cond: bool, what: str) -> None:
-    if not cond:
-        raise ValueError(f"configuration file: {what}")
+def family(config: dict, root: Path = ROOT) -> ModuleType:
+    """The family module a configuration file names under
+    ``"reference"``: ``bench/reference/<module>.py``, with the family's
+    reference (``gaps``), source keys (``SOURCE_KEYS``, ``used_keys``,
+    ``conventions``, ``PUBLISHED_FIELDS``), counts (``counts``) and
+    weight draws (``DRAWS``)."""
+    name = config.get("reference")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise ValueError(
+            f"{config.get('name')}: the configuration file names no family "
+            f"module: it needs \"reference\": \"<module>\" for "
+            f"bench/reference/<module>.py")
+    path = root / "bench" / "reference" / f"{name}.py"
+    source = path.read_bytes()
+    if source not in _FAMILIES:
+        _FAMILIES[source] = _load(path, f"bench_family_{name}")
+    return _FAMILIES[source]
 
 
-def model_config(config: dict):
+def _field(cfg, field: str):
+    """The object holding ``field`` ("a.b": ``cfg.a``'s ``b``) and the
+    attribute's name."""
+    head, _, attr = field.rpartition(".")
+    return (getattr(cfg, head) if head else cfg), head, attr
+
+
+def model_config(config: dict, root: Path = ROOT):
     """The program's ``ModelConfig`` for a configuration file, checked
-    against the file's numbers."""
+    against the file's numbers by the rules of the family it names."""
     from repro.configs import get_arch
 
+    fam = family(config, root)
     cfg = get_arch(config["arch"])
     reduced = set(config.get("reduced", []))
-    moe: dict = {}
-    top: dict = {}
-    for key in _used_keys(config):
-        field = SOURCE_KEYS[key]
-        obj, attr = ((cfg.moe, field[4:]) if field.startswith("moe.")
-                     else (cfg, field))
+    used = fam.used_keys(config)
+    changes: dict[str, dict] = {}   # by "" (the ModelConfig) or a field's name
+    for key in used:
+        obj, head, attr = _field(cfg, fam.SOURCE_KEYS[key])
         want = config[key]
         if key in reduced:
-            (moe if obj is cfg.moe else top)[attr] = type(getattr(obj, attr))(want)
+            changes.setdefault(head, {})[attr] = type(getattr(obj, attr))(want)
         elif getattr(obj, attr) != want:
             raise ValueError(f"{config['name']}: {key}={want} in the file, "
                              f"but the program's {config['arch']} has "
                              f"{attr}={getattr(obj, attr)}")
-    unknown = reduced - set(_used_keys(config))
+    unknown = reduced - set(used)
     if unknown:
         raise ValueError(f"reduced keys the program does not use: {unknown}")
-    # conventions of the family that the source states in words
-    _require(config["hidden_act"] == "silu" and cfg.activation == "swiglu",
-             "a SwiGLU MLP")
-    _require(config["torch_dtype"] == cfg.compute_dtype == "bfloat16",
-             "bf16 weights")
-    _require(cfg.qk_norm and cfg.kv_cache_dtype == "bfloat16",
-             "q/k RMSNorm and a bf16 cache")
-    if "num_experts" in config:
-        _require(config["norm_topk_prob"] and cfg.moe.dispatch == "dense",
-                 "renormalised top-k gates")
-    if moe:
-        top["moe"] = dataclasses.replace(cfg.moe, **moe)
-    return dataclasses.replace(cfg, **top) if top else cfg
+    top = changes.pop("", {})
+    for head, fields in changes.items():
+        top[head] = dataclasses.replace(getattr(cfg, head), **fields)
+    cfg = dataclasses.replace(cfg, **top) if top else cfg
+    for key in reduced & set(fam.PUBLISHED_FIELDS):
+        obj, _, attr = _field(cfg, fam.PUBLISHED_FIELDS[key])
+        want = config["published"][key]
+        if getattr(obj, attr) != want:
+            raise ValueError(f"{config['name']}: {key} was {want} as "
+                             f"published, but the program's {attr} is "
+                             f"{getattr(obj, attr)}")
+    fam.conventions(config, cfg)
+    return cfg

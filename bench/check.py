@@ -2,13 +2,14 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the
-longest, is run through the float32 reference (``bench/reference``):
-each prompt with its served tokens, at once.  For each served token the
-gap is how far its reference logit lies below the reference's best at
-its position.  Greedy serving puts first the token its own bf16 logits
-rank first, so a sound run reads gaps of rounding size; a step that
-loses state, a lane that reads another's cache or an altered token reads
-gaps the size of the logits' own spread.  The numbers are the widest gap
+longest, is run through the float32 reference of the configuration's
+family (``gaps`` of ``bench/reference/<module>.py``): each prompt with
+its served tokens, at once.  For each served token the gap is how far
+its reference logit lies below the reference's best at its position.
+Greedy serving puts first the token its own bf16 logits rank first, so a
+sound run reads gaps of rounding size; a step that loses state, a lane
+that reads another's cache or an altered token reads gaps the size of
+the logits' own spread.  The numbers are the widest gap
 (``logit_gap``), the mean gap (``logit_gap_mean``) and the share of
 tokens that are not the reference's best; the configuration file's
 ``check.limits`` names those a cell compares, with their limits.
@@ -21,7 +22,6 @@ import math
 import numpy as np
 
 from .drive import ReqRec
-from .reference import qwen3
 
 
 def sample(reqs: list[ReqRec], seed: int, tokens: int) -> list[ReqRec]:
@@ -49,15 +49,17 @@ def _stats(gaps: np.ndarray, prefix: str = "") -> dict:
             f"{prefix}not_best_share": float((gaps > 0).mean())}
 
 
-def compare(conf: dict, weights, engine_outputs: dict[int, list[int]],
-            picked: list[ReqRec], control: bool = False) -> dict:
-    """Over the served tokens of the picked requests: the widest gap, the
-    mean gap and the share of tokens that are not the reference's best;
-    with ``control``, the same of the fp8 control's first tokens."""
+def compare(family, conf: dict, weights,
+            engine_outputs: dict[int, list[int]], picked: list[ReqRec],
+            control: bool = False) -> dict:
+    """Over the served tokens of the picked requests, by the family
+    module's reference: the widest gap, the mean gap and the share of
+    tokens that are not the reference's best; with ``control``, the same
+    of the control's first tokens."""
     served, ctl = [np.zeros(0)], [np.zeros(0)]
     for r in picked:
-        s, c = qwen3.gaps(conf, weights, r.prompt, engine_outputs[r.rid],
-                          control=control)
+        s, c = family.gaps(conf, weights, r.prompt, engine_outputs[r.rid],
+                           control=control)
         served.append(s)
         if control:
             ctl.append(c)

@@ -18,6 +18,7 @@ import shutil
 import sys
 import tempfile
 import time
+from types import ModuleType
 from typing import Any, Optional
 
 import jax
@@ -115,6 +116,7 @@ class RunView:
     setup_s: float        # process start to the engine ready to serve
     setup_compile_s: float
     trace: Optional[reduce.Summary]
+    family: ModuleType    # the configuration's family module
 
 
 def build_engine(cfg, weights, slots: int, max_len: int, device):
@@ -158,18 +160,18 @@ def say(*parts) -> None:
 def measure(cell: cells.Cell, seed: int, seconds: float, trace: bool,
             devices, peaks: dict, start: float, *, control: bool = False,
             listen: bool = True) -> dict:
-    """One run.  ``control`` also reads the fp8 control on the same
+    """One run.  ``control`` also reads the family's control on the same
     sample and judges it by the same limits (``result["control"]``);
     ``listen=False`` leaves out the compile and garbage-collection
     listeners.  Neither is used by a benchmark run."""
     log = CompileLog(listen)
     gcs = GcLog(listen)
     conf = cell.config
-    cfg = cells.model_config(conf)
+    cfg = cells.model_config(conf, cell.root)
     dev = devices[0]
     slots, max_len = conf["slots"], conf["max_len"]
     marks = [time.perf_counter()]
-    weights = make_weights(cfg, seed, dev)
+    weights = make_weights(cfg, cell.family, seed, dev)
     jax.block_until_ready(weights)
     marks.append(time.perf_counter())
     engine = build_engine(cfg, weights, slots, max_len, dev)
@@ -218,14 +220,16 @@ def measure(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         shutil.rmtree(tmp, ignore_errors=True)
 
     t_ref = time.perf_counter()
-    numbers = check.compare(conf, weights, outputs, picked, control=control)
+    numbers = check.compare(cell.family, conf, weights, outputs, picked,
+                            control=control)
     ok, checks = check.verdict(conf, numbers)
     say(f"reference: {numbers['requests_compared']} requests, "
         f"{numbers['tokens_compared']} served tokens, "
         f"{time.perf_counter() - t_ref:.3f} s")
 
     view = RunView(run=run, conf=conf, peaks=peaks, setup_s=ready - start,
-                   setup_compile_s=log.seconds(ready), trace=summary)
+                   setup_compile_s=log.seconds(ready), trace=summary,
+                   family=cell.family)
     metrics = {}
     for m in cell.per_layer if trace else cell.end_to_end:
         value = cells.metric_reader(m["name"], cell.root)(view)

@@ -32,7 +32,7 @@ def step_mfu(view):
     done = stats.work(run, run.window)
     if not done.positions[0]:
         return None
-    f = float(reckon.flops(view.conf, done)[0])
+    f = float(reckon.flops(view.family.counts(view.conf), done)[0])
     return 100.0 * f / (run.seconds * view.peaks["bf16_flops_per_s"])
 
 
@@ -49,7 +49,8 @@ def step_roofline(view):
     if k.size == 0 or k[0] == 0:
         return None
     done = stats.work(run, run.step_t1[np.r_[k[0] - 1, k]])
-    bound = np.maximum(reckon.flops(view.conf, done) / view.peaks["bf16_flops_per_s"],
-                       reckon.least_bytes(view.conf, done) / view.peaks["hbm_bytes_per_s"])
+    c = view.family.counts(view.conf)
+    bound = np.maximum(reckon.flops(c, done) / view.peaks["bf16_flops_per_s"],
+                       reckon.least_bytes(c, done) / view.peaks["hbm_bytes_per_s"])
     _, calls, secs = view.trace.main_program()
     return 100.0 * float(bound.mean()) / (secs / calls)

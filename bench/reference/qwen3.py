@@ -19,6 +19,13 @@ inside the layer's program, so the reference fits beside them.
 ``fp8=True`` is the control: the same pass with every matmul's operands
 rounded through float8 e4m3, one absmax scale per token row and per
 weight output column.
+
+It is also the family's module for the benchmark (a configuration file
+names it with ``"reference": "qwen3"``): besides ``gaps``, the source
+keys the program must match (``SOURCE_KEYS``, ``used_keys``,
+``conventions``), the FLOPs and bytes of a step (``counts``) and the
+draws of leaves the generic rules of ``bench/weights.py`` do not cover
+(``DRAWS``, none).
 """
 
 from __future__ import annotations
@@ -30,9 +37,99 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.reckon import Counts
+
 HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
 BUCKET = 256   # sequences are padded to a multiple of this many positions
+BF16 = 2
+
+# source key -> ModelConfig field ("moe." for MoEConfig fields)
+SOURCE_KEYS = {
+    "num_hidden_layers": "num_layers",
+    "hidden_size": "d_model",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim",
+    "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size",
+    "tie_word_embeddings": "tie_embeddings",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "norm_eps",
+    "num_experts": "moe.num_experts",
+    "num_experts_per_tok": "moe.top_k",
+    "moe_intermediate_size": "moe.d_ff",
+}
+# reduced source key -> field that must still equal its published value
+PUBLISHED_FIELDS: dict[str, str] = {}
+DRAWS: dict = {}
+
+
+def _require(cond: bool, what: str) -> None:
+    if not cond:
+        raise ValueError(f"configuration file: {what}")
+
+
+def used_keys(config: dict) -> list[str]:
+    """The source keys of ``config`` that the program's model is built
+    from."""
+    keys = [k for k in SOURCE_KEYS if k in config]
+    if "num_experts" in config:
+        # every layer is sparse, so the dense FFN width is never built
+        _require(config.get("decoder_sparse_step", 1) == 1
+                 and not config.get("mlp_only_layers"),
+                 "the program builds every layer sparse")
+        keys.remove("intermediate_size")
+    return keys
+
+
+def conventions(config: dict, cfg) -> None:
+    """Conventions of the family that the source states in words, held
+    against the program's ``ModelConfig``; raises ``ValueError``."""
+    _require(config["hidden_act"] == "silu" and cfg.activation == "swiglu",
+             "a SwiGLU MLP")
+    _require(config["torch_dtype"] == cfg.compute_dtype == "bfloat16",
+             "bf16 weights")
+    _require(cfg.qk_norm and cfg.kv_cache_dtype == "bfloat16",
+             "q/k RMSNorm and a bf16 cache")
+    if "num_experts" in config:
+        _require(config["norm_topk_prob"] and cfg.moe.dispatch == "dense",
+                 "renormalised top-k gates")
+
+
+def _attn_params(c: dict) -> int:
+    d, hd = c["hidden_size"], c["head_dim"]
+    h, kv = c["num_attention_heads"], c["num_key_value_heads"]
+    return 2 * d * h * hd + 2 * d * kv * hd
+
+
+def _ffn_params(c: dict, routed: bool) -> int:
+    d = c["hidden_size"]
+    if "num_experts" not in c:
+        return 3 * d * c["intermediate_size"]
+    experts = c["num_experts_per_tok"] if routed else c["num_experts"]
+    return d * c["num_experts"] + experts * 3 * d * c["moe_intermediate_size"]
+
+
+def counts(c: dict) -> Counts:
+    """Model FLOPs count each multiply-add as two: the projections, the
+    MLP or the router and the ``num_experts_per_tok`` experts a token is
+    routed to, and the output head over the model's vocabulary per
+    position; attention ``4 * heads * head_dim`` per attended position
+    and layer (scores and weighted values).  Embedding lookups, norms and
+    softmax are left out.  Bytes: every weight once in bf16, and each
+    cached position's keys and values; no recurrent state."""
+    d, layers = c["hidden_size"], c["num_hidden_layers"]
+    matmul = layers * (_attn_params(c) + _ffn_params(c, routed=True))
+    tables = (1 if c["tie_word_embeddings"] else 2) * c["vocab_size"] * d
+    norms = 2 * d + 2 * c["head_dim"]
+    held = _attn_params(c) + _ffn_params(c, routed=False) + norms
+    return Counts(
+        per_position=2 * (matmul + d * c["vocab_size"]),
+        per_attended=4 * layers * c["num_attention_heads"] * c["head_dim"],
+        weights=BF16 * (tables + layers * held + d),
+        cache=BF16 * 2 * layers * c["num_key_value_heads"] * c["head_dim"],
+        state=0)
 
 
 def _q8(x, axis: int):

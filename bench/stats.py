@@ -69,13 +69,14 @@ class Work:
     attended: np.ndarray    # over those positions, the positions each attends
     cached: np.ndarray      # positions in the cache at the interval's end,
     #                         over the requests on a lane in the interval
+    lanes: np.ndarray       # requests on a lane in the interval
     unfinished: int
 
 
 def work(run: Run, edges) -> Work:
     e = np.asarray(edges, float)
     n = e.size - 1
-    tokens, positions, attended, cached = (np.zeros(n) for _ in range(4))
+    tokens, positions, attended, cached, lanes = (np.zeros(n) for _ in range(5))
     unfinished = 0
     for r in run.requests:
         tt = np.asarray(r.token_t, float)
@@ -103,7 +104,8 @@ def work(run: Run, edges) -> Work:
         on = (e[1:] > a) & (e[:-1] < leave)
         fed_back = np.maximum(np.searchsorted(tt, e[1:], side="right") - 1, 0)
         cached += np.where(on, p[1:] + fed_back, 0.0)
-    return Work(tokens, positions, attended, cached, unfinished)
+        lanes += on
+    return Work(tokens, positions, attended, cached, lanes, unfinished)
 
 
 def tok_per_s(run: Run) -> float:

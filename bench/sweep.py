@@ -39,8 +39,8 @@ def main(argv=None) -> int:
     use_compile_cache()
     devices, _ = device.accelerator(cell.chips)
     conf = cell.config
-    cfg = cells.model_config(conf)
-    weights = make_weights(cfg, args.seed, devices[0])
+    cfg = cells.model_config(conf, cell.root)
+    weights = make_weights(cfg, cell.family, args.seed, devices[0])
     engine = measure.build_engine(cfg, weights, conf["slots"], conf["max_len"],
                                   devices[0])
     measure.warm_up(engine, conf["slots"])

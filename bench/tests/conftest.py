@@ -2,7 +2,8 @@
 
 ``smoke_root`` is a checkout-like tree in a temporary directory: its own
 ``BENCHMARK.json`` with two smoke cells (dense chat, MoE offline), their
-configuration and traffic files, and a copy of ``bench/metrics``.  The
+configuration and traffic files, and copies of ``bench/metrics`` and
+``bench/reference``.  The
 configurations keep the Qwen3 architectures and list every width they
 shrink under ``reduced``.
 """
@@ -62,7 +63,9 @@ def write_tree(root: Path) -> Path:
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     for sub in ("configs", "traffic"):
         (root / "bench" / sub).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(ROOT / "bench" / "metrics", root / "bench" / "metrics")
+    for sub in ("metrics", "reference"):
+        shutil.copytree(ROOT / "bench" / sub, root / "bench" / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     configs = []
     for name in ("qwen3-4b", "qwen3-moe-30b-a3b-8l"):
         conf = smoke_config(name)

@@ -1,4 +1,5 @@
-"""FLOP and byte counts of both configurations against hand sums."""
+"""FLOP and byte counts of both configurations against hand sums, as
+their family module (``bench/reference/qwen3.py``) reckons them."""
 
 from __future__ import annotations
 
@@ -10,7 +11,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import reckon, stats
+from bench import cells, reckon, stats
 from bench.drive import ReqRec, Run
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -20,32 +21,38 @@ def conf(name):
     return json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
 
 
+def counts(c) -> reckon.Counts:
+    return cells.family(c).counts(c)
+
+
 def test_qwen3_4b_counts():
-    c = conf("qwen3-4b")
+    c = counts(conf("qwen3-4b"))
     attn = 2 * 2560 * 4096 + 2 * 2560 * 1024          # 26,214,400
     mlp = 3 * 2560 * 9728                              # 74,711,040
-    assert reckon.matmul_flops_per_token(c) == 2 * (
+    assert c.per_position == 2 * (
         36 * (attn + mlp) + 2560 * 151936) == 8_044_544_000
-    assert reckon.attn_flops_per_position(c) == 4 * 36 * 32 * 128
+    assert c.per_attended == 4 * 36 * 32 * 128
     norms = 2 * 2560 + 2 * 128
-    assert reckon.weight_bytes(c) == 2 * (
+    assert c.weights == 2 * (
         151936 * 2560 + 36 * (attn + mlp + norms) + 2560) == 8_044_936_192
-    assert reckon.kv_bytes_per_position(c) == 147_456
+    assert c.cache == 147_456
+    assert c.state == 0
 
 
 def test_qwen3_moe_8l_counts():
-    c = conf("qwen3-moe-30b-a3b-8l")
+    c = counts(conf("qwen3-moe-30b-a3b-8l"))
     attn = 2 * 2048 * 4096 + 2 * 2048 * 512           # 18,874,368
     expert = 3 * 2048 * 768                            # 4,718,592
     routed = 2048 * 128 + 8 * expert
-    assert reckon.matmul_flops_per_token(c) == 2 * (
+    assert c.per_position == 2 * (
         8 * (attn + routed) + 2048 * 151936) == 1_532_493_824
-    assert reckon.attn_flops_per_position(c) == 4 * 8 * 32 * 128
+    assert c.per_attended == 4 * 8 * 32 * 128
     held = 2048 * 128 + 128 * expert
     norms = 2 * 2048 + 2 * 128
-    assert reckon.weight_bytes(c) == 2 * (
+    assert c.weights == 2 * (
         2 * 151936 * 2048 + 8 * (attn + held + norms) + 2048) == 11_214_594_048
-    assert reckon.kv_bytes_per_position(c) == 16_384
+    assert c.cache == 16_384
+    assert c.state == 0
 
 
 @pytest.mark.parametrize("name, extra", [
@@ -61,7 +68,7 @@ def test_weight_bytes_match_the_served_tree(name, extra):
     shapes = weights.param_shapes(cells.model_config(c))
     stored = sum(math.prod(a.shape) * a.dtype.itemsize
                  for a in jax.tree.leaves(shapes))
-    assert stored == reckon.weight_bytes(c) + extra
+    assert stored == counts(c).weights + extra
 
 
 def test_flops_and_least_bytes_of_the_work_of_each_step():
@@ -73,7 +80,7 @@ def test_flops_and_least_bytes_of_the_work_of_each_step():
               window=(0.0, 1.0), attempted=[a], drain_end=1.0)
     w = stats.work(run, [0.1, 0.2, 0.3, 0.4, 0.5])
     positions, attended, cached = [4 / 3, 1, 1, 0], [22 / 9, 3, 4, 0], [2, 3, 4, 0]
-    c = conf("qwen3-4b")
+    c = counts(conf("qwen3-4b"))
     np.testing.assert_allclose(
         reckon.flops(c, w),
         8_044_544_000 * np.array(positions) + 4 * 36 * 32 * 128 * np.array(attended))

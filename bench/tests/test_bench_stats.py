@@ -76,7 +76,7 @@ def test_tok_per_s_credits_prompt_steps_and_emitted_tokens():
 def test_a_request_that_never_reached_a_lane_credits_nothing():
     waiting = rec(3, due=0.0, prompt_len=4, out_len=2, admit_step=-1)
     w = stats.work(run_of([waiting]), [0.0, 0.5, 1.0])
-    for part in (w.tokens, w.positions, w.attended, w.cached):
+    for part in (w.tokens, w.positions, w.attended, w.cached, w.lanes):
         np.testing.assert_array_equal(part, 0)
     assert w.unfinished == 0
 
@@ -94,6 +94,17 @@ def test_work_prorates_the_prompt_and_feeds_outputs_back():
     np.testing.assert_allclose(w.attended, [5 / 9, 22 / 9, 3, 4])
     np.testing.assert_allclose(w.cached, [2 / 3, 2, 3, 4])
     assert stats.work(run, [0.4, 0.5]).cached[0] == 0    # off its lane
+
+
+def test_work_counts_the_requests_on_a_lane_beside_their_cache():
+    # a: prompt 2 on a lane from 0.05, outputs at 0.2, 0.3, 0.4, then off
+    # b: prompt 3 on a lane from 0.35, outputs at 0.6, 0.7
+    a = rec(0, due=0.0, prompt_len=2, out_len=3, admit_step=0, first_step=1)
+    b = rec(1, due=0.0, prompt_len=3, out_len=2, admit_step=3, first_step=5)
+    w = stats.work(run_of([a, b]), [0.0, 0.2, 0.4, 0.6, 0.8])
+    np.testing.assert_array_equal(w.lanes, [1, 2, 1, 1])
+    # a: 2, then 2 + 2 fed back; b: 0.6 of its prompt, 3, then 3 + 1
+    np.testing.assert_allclose(w.cached, [2, 4.6, 3, 4])
 
 
 class OneStepPrefill:
