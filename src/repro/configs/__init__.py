@@ -12,6 +12,7 @@ from .base import (  # noqa: F401
 
 from .granite_moe_1b_a400m import CONFIG as _granite_moe
 from .qwen3_moe_30b_a3b import CONFIG as _qwen3_moe
+from .qwen3_next_80b_a3b import CONFIG as _qwen3_next
 from .xlstm_1_3b import CONFIG as _xlstm
 from .stablelm_3b import CONFIG as _stablelm
 from .codeqwen1_5_7b import CONFIG as _codeqwen
@@ -25,7 +26,7 @@ ARCHS: dict[str, ModelConfig] = {
     c.name: c
     for c in (
         _granite_moe, _qwen3_moe, _xlstm, _stablelm, _codeqwen,
-        _granite20b, _qwen3_4b, _internvl2, _musicgen, _rgemma,
+        _granite20b, _qwen3_4b, _internvl2, _musicgen, _rgemma, _qwen3_next,
     )
 }
 

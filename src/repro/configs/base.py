@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 Family = Literal["dense", "moe", "ssm", "hybrid", "vlm", "audio"]
-BlockKind = Literal["attn", "local_attn", "mlstm", "slstm", "rglru"]
+BlockKind = Literal["attn", "local_attn", "mlstm", "slstm", "rglru", "gdn"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +29,16 @@ class MoEConfig:
     # 'dense' = all-experts compute, gate-combined (roofline baseline);
     # 'ragged' = sort-based dispatch feeding DLS-planned expert tiles
     dispatch: Literal["dense", "ragged"] = "dense"
+    # experts this layer holds (0 => all): the router still scores all
+    # num_experts, and only the held experts' part is computed (an
+    # expert-parallel share: experts 0 .. held-1)
+    held: int = 0
+    # one always-on expert of this width behind a sigmoid gate (0 => none)
+    shared_d_ff: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +64,16 @@ class ModelConfig:
     # recurrent dims
     lru_width: int = 0              # RG-LRU width (0 => d_model)
     conv_width: int = 4             # temporal conv in recurrent blocks
+    # Gated DeltaNet ('gdn') heads: key heads, value heads (a multiple of
+    # the key heads) and their widths
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    # attention: share of each head's dims that is rotated, and a
+    # per-channel sigmoid output gate projected beside each query head
+    rotary_fraction: float = 1.0
+    attn_gate: bool = False
     # modality stub: number of precomputed prefix embeddings (VLM patches /
     # audio conditioning frames) supplied by input_specs()
     prefix_len: int = 0
@@ -105,6 +125,10 @@ class ModelConfig:
         return self.num_kv_heads * self.resolved_head_dim
 
     @property
+    def rotary_dim(self) -> int:
+        return int(self.resolved_head_dim * self.rotary_fraction)
+
+    @property
     def pattern_layers(self) -> tuple[BlockKind, ...]:
         """Full per-layer block kinds (pattern tiled over num_layers)."""
         reps = math.ceil(self.num_layers / len(self.block_pattern))
@@ -126,6 +150,8 @@ class ModelConfig:
         for kind in self.pattern_layers:
             if kind in ("attn", "local_attn"):
                 total += d * self.num_heads * hd  # q
+                if self.attn_gate:
+                    total += d * self.num_heads * hd  # output gate
                 total += 2 * d * self.num_kv_heads * hd  # k,v
                 total += self.num_heads * hd * d  # o
                 if self.qk_norm:
@@ -143,10 +169,19 @@ class ModelConfig:
                 total += w * self.conv_width  # conv
                 total += 3 * w  # lambda + input/rec gates (diagonal-ish)
                 total += d
+            elif kind == "gdn":
+                kd = self.gdn_key_heads * self.gdn_key_dim
+                vd = self.gdn_value_heads * self.gdn_value_dim
+                total += d * (2 * kd + 2 * vd + 2 * self.gdn_value_heads)
+                total += (2 * kd + vd) * self.conv_width  # conv
+                total += 2 * self.gdn_value_heads + self.gdn_value_dim
+                total += vd * d + d  # out + pre-norm
             if self.moe is not None:
                 e = self.moe
                 total += d * e.num_experts  # router
-                total += e.num_experts * self._ffn_params(d, e.d_ff)
+                total += e.num_held * self._ffn_params(d, e.d_ff)
+                if e.shared_d_ff:
+                    total += self._ffn_params(d, e.shared_d_ff) + d
                 total += d
             elif self.d_ff > 0:
                 total += self._ffn_params(d, self.d_ff)
@@ -161,7 +196,7 @@ class ModelConfig:
         e = self.moe
         dense_like = self.param_count()
         per_expert = self._ffn_params(self.d_model, e.d_ff)
-        inactive = (e.num_experts - e.top_k) * per_expert * self.num_layers
+        inactive = (e.num_held - e.top_k) * per_expert * self.num_layers
         return dense_like - inactive
 
     def _ffn_params(self, d: int, ff: int) -> int:
@@ -237,10 +272,17 @@ def smoke_config(model: ModelConfig) -> ModelConfig:
     few experts, tiny vocab; same block pattern and code paths."""
     moe = None
     if model.moe is not None:
+        e = model.moe
+        experts = min(e.num_experts, 4)
         moe = dataclasses.replace(
-            model.moe, num_experts=min(model.moe.num_experts, 4),
-            top_k=min(model.moe.top_k, 2), d_ff=32,
+            e, num_experts=experts, top_k=min(e.top_k, 2), d_ff=32,
+            held=e.held and max(1, e.held * experts // e.num_experts),
+            shared_d_ff=e.shared_d_ff and 32,
         )
+    gdn = {}
+    if model.gdn_key_heads:
+        gdn = dict(gdn_key_heads=2, gdn_value_heads=2 * model.gdn_value_heads
+                   // model.gdn_key_heads, gdn_key_dim=16, gdn_value_dim=16)
     pat_period = len(model.block_pattern)
     # cover the group-scan path: >= 1 full pattern group
     smoke_layers = 2 * pat_period if pat_period <= 3 else pat_period
@@ -260,4 +302,5 @@ def smoke_config(model: ModelConfig) -> ModelConfig:
         moe=moe,
         moe_groups=2,
         remat="none",
+        **gdn,
     )

@@ -1,6 +1,7 @@
-"""GQA/MQA attention: RoPE, optional qk-norm, causal + sliding-window
-masks, memory-bounded flash-style KV-block streaming for long sequences,
-and a ring-buffer KV cache for decode.
+"""GQA/MQA attention: RoPE (optionally on a leading share of each head's
+dims), optional qk-norm, an optional sigmoid output gate, causal +
+sliding-window masks, memory-bounded flash-style KV-block streaming for
+long sequences, and a ring-buffer KV cache for decode.
 
 Layout note: KV heads are broadcast to the full query-head count before
 the score einsums ("repeat-KV").  This keeps every score/context tensor
@@ -34,8 +35,10 @@ NEG_INF = -1e30
 def init_attention(key, cfg):
     hd = cfg.resolved_head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
+    # with an output gate, q_proj gives [query, gate] per head
+    q_out = cfg.num_heads * hd * (2 if cfg.attn_gate else 1)
     params = {
-        "wq": dense_init(k1, cfg.d_model, cfg.num_heads * hd, "embed", "heads")[0],
+        "wq": dense_init(k1, cfg.d_model, q_out, "embed", "heads")[0],
         "wk": dense_init(k2, cfg.d_model, cfg.num_kv_heads * hd, "embed", "kv_heads")[0],
         "wv": dense_init(k3, cfg.d_model, cfg.num_kv_heads * hd, "embed", "kv_heads")[0],
         "wo": dense_init(k4, cfg.num_heads * hd, cfg.d_model, "heads", "embed")[0],
@@ -89,8 +92,8 @@ def kv_cache_specs(cfg, batch: int, max_len: int, window: int = 0,
 class KVCacheQ(NamedTuple):
     """Int8-quantized KV cache (per-token, per-kv-head max-abs scales).
 
-    Halves decode HBM traffic — the memory-bound decode hillclimb lever
-    (EXPERIMENTS.md §Perf, codeqwen decode_32k)."""
+    Halves decode HBM traffic — the memory-bound decode lever for
+    long-context dense models (codeqwen decode_32k)."""
 
     k: jax.Array        # int8 (b, S, kvh, hd)
     v: jax.Array
@@ -139,18 +142,40 @@ def _project_qkv(params, cfg, x, sin, cos):
     wq = use_weight(params["wq"].astype(dt), cfg, None, "heads")
     wk = use_weight(params["wk"].astype(dt), cfg, None, "kv_heads")
     wv = use_weight(params["wv"].astype(dt), cfg, None, "kv_heads")
-    q = (x @ wq).reshape(b, s, cfg.num_heads, hd)
+    gate = None
+    if cfg.attn_gate:
+        q, gate = jnp.split((x @ wq).reshape(b, s, cfg.num_heads, 2 * hd), 2,
+                            axis=-1)
+        gate = gate.reshape(b, s, cfg.num_heads * hd)
+    else:
+        q = (x @ wq).reshape(b, s, cfg.num_heads, hd)
     k = (x @ wk).reshape(b, s, cfg.num_kv_heads, hd)
     v = (x @ wv).reshape(b, s, cfg.num_kv_heads, hd)
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         k = rms_norm(k, params["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    q = _rotate(q, sin, cos, cfg.rotary_dim)
+    k = _rotate(k, sin, cos, cfg.rotary_dim)
     q = shard_as(q, "batch", "seq", "heads", "head_dim")
     k = shard_as(k, "batch", "seq", "kv_heads", "head_dim")
     v = shard_as(v, "batch", "seq", "kv_heads", "head_dim")
-    return q, k, v
+    return q, k, v, gate
+
+
+def _rotate(x, sin, cos, rotary_dim: int):
+    """Rotary embedding of the first ``rotary_dim`` dims of each head; the
+    rest pass through."""
+    if rotary_dim == x.shape[-1]:
+        return apply_rope(x, sin, cos)
+    return jnp.concatenate([apply_rope(x[..., :rotary_dim], sin, cos),
+                            x[..., rotary_dim:]], axis=-1)
+
+
+def _gated(ctx, gate):
+    """The attention output times sigmoid(gate), where there is a gate."""
+    if gate is None:
+        return ctx
+    return ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
 
 
 def _repeat_kv(k: jax.Array, num_heads: int) -> jax.Array:
@@ -239,12 +264,12 @@ def _attend_flash(q, k, v, cfg, window: int, block: int = 1024):
 def attention(params, cfg, x, sin, cos, *, window: int = 0):
     """Train/prefill attention.  x: (b, s, d) -> (b, s, d)."""
     b, s, _ = x.shape
-    q, k, v = _project_qkv(params, cfg, x, sin, cos)
+    q, k, v, gate = _project_qkv(params, cfg, x, sin, cos)
     if s > cfg.flash_threshold:
         ctx = _attend_flash(q, k, v, cfg, window)
     else:
         ctx = _attend_full(q, k, v, cfg, window)
-    ctx = ctx.reshape(b, s, cfg.num_heads * cfg.resolved_head_dim)
+    ctx = _gated(ctx.reshape(b, s, cfg.num_heads * cfg.resolved_head_dim), gate)
     wo = use_weight(params["wo"].astype(x.dtype), cfg, "heads", None)
     out = ctx @ wo
     return shard_as(out, "batch", "seq", "embed_act")
@@ -257,7 +282,7 @@ def attention_decode(params, cfg, x, sin, cos, cache,
     b, s, _ = x.shape
     assert s == 1
     hd = cfg.resolved_head_dim
-    q, k, v = _project_qkv(params, cfg, x, sin, cos)
+    q, k, v, gate = _project_qkv(params, cfg, x, sin, cos)
     size = cache.k.shape[1]
     ring = window > 0
     # per-lane positions: each batch lane writes at its own slot (true
@@ -313,7 +338,7 @@ def attention_decode(params, cfg, x, sin, cos, cache,
                          new_v.astype(jnp.float32)).astype(q.dtype)
     else:
         ctx = jnp.einsum("bkgt,btkd->bkgd", probs.astype(q.dtype), vf)
-    ctx = ctx.reshape(b, 1, h * hd)
+    ctx = _gated(ctx.reshape(b, 1, h * hd), gate)
     out = ctx @ params["wo"].astype(x.dtype)
     out = shard_as(out, "batch", "seq", "embed_act")
     if quant:

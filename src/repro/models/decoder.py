@@ -1,4 +1,4 @@
-"""Unified decoder LM covering all 10 assigned architectures.
+"""Unified decoder LM covering every registered architecture.
 
 Layer stacking: the block pattern (e.g. ('attn',) or ('rglru','rglru',
 'local_attn') or 7x'mlstm'+1x'slstm') is tiled over num_layers as
@@ -13,6 +13,7 @@ per pattern position and scanned the same way.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, NamedTuple, Optional
 
@@ -30,6 +31,14 @@ from .attention import (
     init_kv_cache_q,
     kv_cache_q_specs,
     kv_cache_specs,
+)
+from .deltanet import (
+    GDNState,
+    gdn,
+    gdn_decode,
+    gdn_state_specs,
+    init_gdn,
+    init_gdn_state,
 )
 from .layers import (
     embed_init,
@@ -69,11 +78,35 @@ _MIXER_INIT = {
     "mlstm": init_mlstm,
     "slstm": init_slstm,
     "rglru": init_rglru,
+    "gdn": init_gdn,
 }
 
 
 def _has_ffn(cfg) -> bool:
     return cfg.d_ff > 0 or cfg.moe is not None
+
+
+def _scope(name: str, on: bool):
+    """A named scope, so that the device trace names the ops, where the
+    layer is one of the newer kinds (the others' programs stay as they
+    were)."""
+    return jax.named_scope(name) if on else contextlib.nullcontext()
+
+
+def _mixer_scope(cfg, kind: str):
+    if kind == "gdn":
+        return _scope("gdn", True)
+    return _scope("gated_attn", kind in ("attn", "local_attn") and cfg.attn_gate)
+
+
+def _ffn(params, cfg, x):
+    """The block's FFN (MoE or dense) of the normed x."""
+    if cfg.moe is not None:
+        e = cfg.moe
+        with _scope("moe_share", bool(e.held or e.shared_d_ff)):
+            y, aux, _load = moe(params, cfg, x)
+        return y, aux
+    return mlp(params, cfg, x), jnp.zeros((), jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -105,51 +138,51 @@ def block_apply(params, cfg, kind: str, x, sin, cos):
     """Training/prefill block: returns (x, aux_loss)."""
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     window = cfg.window if kind == "local_attn" else 0
-    if kind in ("attn", "local_attn"):
-        mix = attention(params["mixer"], cfg, h, sin, cos, window=window)
-    elif kind == "mlstm":
-        mix, _ = mlstm_parallel(params["mixer"], cfg, h)
-    elif kind == "slstm":
-        mix, _ = slstm(params["mixer"], cfg, h)
-    elif kind == "rglru":
-        mix, _ = rglru(params["mixer"], cfg, h)
-    else:
-        raise KeyError(kind)
+    with _mixer_scope(cfg, kind):
+        if kind in ("attn", "local_attn"):
+            mix = attention(params["mixer"], cfg, h, sin, cos, window=window)
+        elif kind == "mlstm":
+            mix, _ = mlstm_parallel(params["mixer"], cfg, h)
+        elif kind == "slstm":
+            mix, _ = slstm(params["mixer"], cfg, h)
+        elif kind == "rglru":
+            mix, _ = rglru(params["mixer"], cfg, h)
+        elif kind == "gdn":
+            mix, _ = gdn(params["mixer"], cfg, h)
+        else:
+            raise KeyError(kind)
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
-    if cfg.moe is not None:
+    if _has_ffn(cfg):
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        y, aux_l, _load = moe(params["ffn"], cfg, h2)
+        y, aux_l = _ffn(params["ffn"], cfg, h2)
         x = x + y
         aux = aux + aux_l
-    elif cfg.d_ff > 0:
-        h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        x = x + mlp(params["ffn"], cfg, h2)
     return x, aux
 
 
 def block_decode(params, cfg, kind: str, x, sin, cos, cache):
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     window = cfg.window if kind == "local_attn" else 0
-    if kind in ("attn", "local_attn"):
-        mix, cache = attention_decode(params["mixer"], cfg, h, sin, cos,
-                                      cache, window=window)
-    elif kind == "mlstm":
-        mix, cache = mlstm_decode(params["mixer"], cfg, h, cache)
-    elif kind == "slstm":
-        y, cache = slstm_decode(params["mixer"], cfg, h, cache)
-        mix = y
-    elif kind == "rglru":
-        mix, cache = rglru_decode(params["mixer"], cfg, h, cache)
-    else:
-        raise KeyError(kind)
+    with _mixer_scope(cfg, kind):
+        if kind in ("attn", "local_attn"):
+            mix, cache = attention_decode(params["mixer"], cfg, h, sin, cos,
+                                          cache, window=window)
+        elif kind == "mlstm":
+            mix, cache = mlstm_decode(params["mixer"], cfg, h, cache)
+        elif kind == "slstm":
+            y, cache = slstm_decode(params["mixer"], cfg, h, cache)
+            mix = y
+        elif kind == "rglru":
+            mix, cache = rglru_decode(params["mixer"], cfg, h, cache)
+        elif kind == "gdn":
+            mix, cache = gdn_decode(params["mixer"], cfg, h, cache)
+        else:
+            raise KeyError(kind)
     x = x + mix
     if _has_ffn(cfg):
         h2 = rms_norm(x, params["norm2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            y, _aux, _load = moe(params["ffn"], cfg, h2)
-        else:
-            y = mlp(params["ffn"], cfg, h2)
+        y, _aux = _ffn(params["ffn"], cfg, h2)
         x = x + y
     return x, cache
 
@@ -257,8 +290,8 @@ def forward(params, cfg, tokens, prefix_embed=None):
     if prefix_embed is not None:
         x = jnp.concatenate([prefix_embed.astype(compute), x], axis=1)
     b, s, _ = x.shape
-    hd = cfg.resolved_head_dim
-    sin, cos = rope_tables(jnp.arange(s), hd, cfg.rope_theta, jnp.float32)
+    sin, cos = rope_tables(jnp.arange(s), cfg.rotary_dim, cfg.rope_theta,
+                           jnp.float32)
 
     g, pattern, remainder = _group_split(cfg)
     aux0 = jnp.zeros((), jnp.float32)
@@ -293,8 +326,8 @@ def _hidden_states(params, cfg, tokens, prefix_embed=None):
     if prefix_embed is not None:
         x = jnp.concatenate([prefix_embed.astype(compute), x], axis=1)
     b, s, _ = x.shape
-    hd = cfg.resolved_head_dim
-    sin, cos = rope_tables(jnp.arange(s), hd, cfg.rope_theta, jnp.float32)
+    sin, cos = rope_tables(jnp.arange(s), cfg.rotary_dim, cfg.rope_theta,
+                           jnp.float32)
     g, pattern, remainder = _group_split(cfg)
     aux0 = jnp.zeros((), jnp.float32)
     if g > 0:
@@ -380,6 +413,8 @@ def _cache_for(cfg, kind: str, batch: int, max_len: int, spec: bool):
         return (slstm_state_specs if spec else init_slstm_state)(cfg, batch)
     if kind == "rglru":
         return (rglru_state_specs if spec else init_rglru_state)(cfg, batch)
+    if kind == "gdn":
+        return (gdn_state_specs if spec else init_gdn_state)(cfg, batch)
     raise KeyError(kind)
 
 
@@ -429,6 +464,9 @@ def _cache_axes_for(cfg, kind: str):
                           h=Ax("batch", None), m=Ax("batch", None))
     if kind == "rglru":
         return RGLRUState(h=Ax("batch", "lru"), conv=Ax("batch", None, "lru"))
+    if kind == "gdn":
+        return GDNState(conv=Ax("batch", None, None),
+                        s=Ax("batch", "heads", None, None))
     raise KeyError(kind)
 
 
@@ -449,9 +487,8 @@ def decode_step(params, cfg, state: DecodeState, tokens):
     """tokens (b, 1) -> (logits (b, 1, v), new state)."""
     compute = jnp.dtype(cfg.compute_dtype)
     x = embed_tokens(params["embed"], tokens, compute)
-    hd = cfg.resolved_head_dim
-    # per-lane rope phase: (b, 1, hd/2)
-    sin, cos = rope_tables(state.pos[:, None], hd, cfg.rope_theta,
+    # per-lane rope phase over the rotated dims: (b, 1, rotary_dim/2)
+    sin, cos = rope_tables(state.pos[:, None], cfg.rotary_dim, cfg.rope_theta,
                            jnp.float32)
     g, pattern, remainder = _group_split(cfg)
 

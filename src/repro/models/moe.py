@@ -1,6 +1,6 @@
 """Top-k Mixture-of-Experts with DLS-driven load balancing.
 
-The LB4OMP mapping (DESIGN.md §2): experts are *workers*, tokens are *loop
+The LB4OMP mapping: experts are *workers*, tokens are *loop
 iterations*, and the router's per-expert load raggedness is exactly the
 load-imbalance problem the paper's techniques address.
 
@@ -21,6 +21,12 @@ Dispatch implementations:
     into (E, C, d) tiles with DLS-planned capacity.  This is the layout
     consumed by the grouped-matmul Pallas kernel
     (repro.kernels.grouped_matmul) and the §Perf optimized path.
+
+A layer may hold a share of the experts (``MoEConfig.held``, the dense
+path only): the router scores all ``num_experts`` and picks the top-k
+among them, and only the held experts' part of the gated sum is
+computed; what the other experts would add is left out.  A shared expert
+(``MoEConfig.shared_d_ff``) is added once, behind its sigmoid gate.
 """
 
 from __future__ import annotations
@@ -40,10 +46,9 @@ def init_moe(key, cfg):
     k_r, k_i, k_g, k_o = jax.random.split(key, 4)
 
     def expert_stack(k, a, b):
-        ks = jax.random.split(k, e.num_experts)
         scale = (1.0 / a) ** 0.5
         w = jax.random.truncated_normal(
-            k, -2.0, 2.0, (e.num_experts, a, b), jnp.float32)
+            k, -2.0, 2.0, (e.num_held, a, b), jnp.float32)
         return w * scale
 
     params = {
@@ -61,6 +66,16 @@ def init_moe(key, cfg):
     if gated:
         params["wg"] = expert_stack(k_g, d, ff)
         axes["wg"] = Ax("experts", "embed", "expert_mlp")
+    if e.shared_d_ff:
+        ks = jax.random.split(k_r, 4)
+        params["shared"] = {
+            "wi": dense_init(ks[1], d, e.shared_d_ff, "embed", "mlp")[0],
+            "wg": dense_init(ks[2], d, e.shared_d_ff, "embed", "mlp")[0],
+            "wo": dense_init(ks[3], e.shared_d_ff, d, "mlp", "embed")[0]}
+        axes["shared"] = {"wi": Ax("embed", "mlp"), "wg": Ax("embed", "mlp"),
+                          "wo": Ax("mlp", "embed")}
+        params["shared_gate"] = dense_init(ks[0], d, 1, "embed", None)[0]
+        axes["shared_gate"] = Ax("embed", None)
     return params, axes
 
 
@@ -123,17 +138,20 @@ def moe_dense(params, cfg, x, expert_chunk: int | None = None):
     e = cfg.moe
     idx, gate, aux, load = _route(params, cfg, x)
     dt = x.dtype
+    held = e.num_held
     if expert_chunk is None:
-        expert_chunk = expert_chunk_for(b * s, e.num_experts, e.d_ff,
+        expert_chunk = expert_chunk_for(b * s, held, e.d_ff,
                                         jnp.dtype(dt).itemsize)
-    ec = min(expert_chunk, e.num_experts)
-    assert e.num_experts % ec == 0
-    nchunk = e.num_experts // ec
+    ec = min(expert_chunk, held)
+    assert held % ec == 0
+    nchunk = held // ec
     # per-token weight for every expert (0 if not selected)
     wfull = jnp.zeros((b, s, e.num_experts), jnp.float32)
     bidx = jnp.arange(b)[:, None, None]
     sidx = jnp.arange(s)[None, :, None]
     wfull = wfull.at[bidx, sidx, idx].add(gate)
+    if held < e.num_experts:
+        wfull = wfull[..., :held]
     wg = params.get("wg")
 
     def experts(wi_c, wo_c, wg_c, w_c):
@@ -171,15 +189,15 @@ def moe_ragged(params, cfg, x):
 
     Iteration 1 (global sort-gather) removed the E/top_k compute inflation
     but let GSPMD all-gather the full token matrix every layer (the sort
-    indices cross data shards) — wire bytes grew 4.7x.  REFUTED; see
-    EXPERIMENTS.md §Perf.  This version keeps dispatch LOCAL: tokens are
-    split into `moe_groups` groups along the batch dim (groups == data
-    shards), each group sorts/gathers its own tokens into (E, C_g, d)
-    tiles, and only the expert dimension crosses devices (the standard
+    indices cross data shards) — wire bytes grew 4.7x.  This version keeps
+    dispatch LOCAL: tokens are split into `moe_groups` groups along the
+    batch dim (groups == data shards), each group sorts/gathers its own
+    tokens into (E, C_g, d) tiles, and only the expert dimension crosses devices (the standard
     MoE all-to-all pattern, inferred by GSPMD from the sharding specs).
     """
     b, s, d = x.shape
     e = cfg.moe
+    assert e.num_held == e.num_experts, "ragged dispatch holds every expert"
     idx, gate, aux, load = _route(params, cfg, x)
     groups = min(cfg.moe_groups, b)
     while b % groups != 0:
@@ -229,7 +247,7 @@ def moe_ragged(params, cfg, x):
     # expert-sharded with zero dispatch collectives, and the only wire
     # cost is one partial-sum all-reduce of the combined output per layer.
     # (Iterations A3/A4 — capacity-shard + axis-swap all-to-all — left
-    # ~10 GiB/layer of residual gathers; see EXPERIMENTS.md.)
+    # ~10 GiB/layer of residual gathers.)
     xe = shard_as(xe, "moe_group", None, None, "embed_act")
     dt = x.dtype
     wi = use_weight(params["wi"].astype(dt), cfg, "experts", None, "expert_mlp")
@@ -255,7 +273,22 @@ def moe_ragged(params, cfg, x):
     return shard_as(y, "batch", "seq", "embed_act"), aux, load
 
 
+def shared_expert(params, cfg, x):
+    """The shared expert's SwiGLU output times sigmoid(shared_gate . x)."""
+    dt = x.dtype
+    sp = params["shared"]
+    h = activate(x @ sp["wg"].astype(dt), x @ sp["wi"].astype(dt),
+                 cfg.activation)
+    gate = jax.nn.sigmoid((x @ params["shared_gate"].astype(dt)).astype(
+        jnp.float32)).astype(dt)
+    return gate * (h @ sp["wo"].astype(dt))
+
+
 def moe(params, cfg, x):
     if cfg.moe.dispatch == "ragged":
-        return moe_ragged(params, cfg, x)
-    return moe_dense(params, cfg, x)
+        y, aux, load = moe_ragged(params, cfg, x)
+    else:
+        y, aux, load = moe_dense(params, cfg, x)
+    if cfg.moe.shared_d_ff:
+        y = y + shared_expert(params, cfg, x)
+    return y, aux, load

@@ -1,7 +1,7 @@
 """Recurrent mixers: mLSTM / sLSTM (xLSTM, arXiv:2405.04517) and RG-LRU
 (RecurrentGemma / Griffin, arXiv:2402.19427).
 
-TPU adaptation notes (DESIGN.md §2): training/prefill uses parallel forms
+TPU adaptation notes: training/prefill uses parallel forms
 (chunkwise mLSTM with carried (C, n, m) state; associative-scan RG-LRU);
 decode uses O(1) recurrent state updates.  sLSTM has no parallel form
 (hidden-to-hidden recurrence) and is scanned over time — the xLSTM pattern

@@ -3,8 +3,9 @@
 The TPU compiler refuses what interpret mode accepts: Mosaic layouts a
 kernel cannot use, and programs that do not fit the device's memory.
 These tests compile the three Pallas kernels at real widths, and the
-full-width qwen3-4b and 8-layer qwen3-moe-30b-a3b decode steps exactly
-as ``DecodeEngine`` jits them.
+full-width qwen3-4b, 8-layer qwen3-moe-30b-a3b and 8-layer
+qwen3-next-80b-a3b (128 of 512 experts held) decode steps exactly as
+``DecodeEngine`` jits them.
 A compile is not a run: nothing here says anything about results or
 times.
 
@@ -14,6 +15,7 @@ imports this file.
 """
 
 import dataclasses
+import hashlib
 import math
 import re
 
@@ -168,3 +170,73 @@ def test_qwen3_4b_serving_init_builds_bf16_on_chip(one_chip):
     assert mem.temp_size_in_bytes < 0.05 * f32_tree_bytes
     assert mem.output_size_in_bytes + mem.temp_size_in_bytes < (
         V5E_PROGRAM_BYTES)
+
+
+def _decode_step(cfg, lanes: int, max_len: int, one_chip):
+    from repro.launch.serve import serving_init
+    from repro.models import init_decode_state
+    from repro.serve.engine import decode_program
+
+    def place(tree):
+        return jax.tree.map(
+            lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = place(jax.eval_shape(serving_init(cfg), 0))
+    state = place(init_decode_state(cfg, lanes, max_len=max_len, spec=True))
+    tokens = _spec((lanes, 1), jnp.int32, one_chip)
+    return decode_program(cfg).lower(params, state, tokens).compile(), state
+
+
+def _hlo_digest(compiled) -> str:
+    """sha256 of the compiled module's text without its metadata (op
+    names, source lines) and the file and stack-frame tables, which
+    follow the program's source files and not what the program does."""
+    lines = compiled.as_text().splitlines()
+    first = next(i for i, ln in enumerate(lines)
+                 if ln.startswith(("%", "ENTRY")))
+    text = "\n".join([lines[0]] + lines[first:])
+    text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+# computed on the tree before the Qwen3-Next block kinds, gate, partial
+# rotary and expert share were added (same JAX and TPU compiler)
+DECODE_HLO = {
+    ("qwen3-4b", 36, 8):
+        "d8dd43a2ee1defd4aa513dd1a51d10e0af82725bbafc69144e20b706a08327c5",
+    ("qwen3-moe-30b-a3b", 8, 32):
+        "375a9f2b743baa5f7a154bb6f3174248fbb6cf13789bb58084a21e291ca79a13",
+}
+
+
+@pytest.mark.parametrize("arch,layers,lanes", sorted(DECODE_HLO))
+def test_existing_decode_steps_compile_to_the_same_hlo(one_chip, arch, layers,
+                                                       lanes):
+    """The benchmark's qwen3-4b and qwen3-moe-30b-a3b-8l decode steps
+    (max_len 2048) compile as before: Qwen3-Next's additions are off by
+    default and leave these programs as they were."""
+    from repro.configs import get_arch
+
+    cfg = dataclasses.replace(get_arch(arch), num_layers=layers)
+    compiled, _ = _decode_step(cfg, lanes, 2048, one_chip)
+    assert _hlo_digest(compiled) == DECODE_HLO[(arch, layers, lanes)]
+
+
+def test_qwen3_next_longgen_decode_step_fits_one_chip(one_chip):
+    """qwen3-next-80b-a3b as the longgen cell runs it: 8 layers (6 Gated
+    DeltaNet, 2 gated attention), 128 of 512 experts held, 128 lanes x
+    4096, bf16: the step program fits one chip, and the donated DeltaNet
+    state and KV cache are aliased instead of double-buffered."""
+    from repro.configs import get_arch
+
+    base = get_arch("qwen3-next-80b-a3b")
+    cfg = dataclasses.replace(base, num_layers=8,
+                              moe=dataclasses.replace(base.moe, held=128))
+    compiled, state = _decode_step(cfg, 128, 4096, one_chip)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                      for leaf in jax.tree.leaves(state))
+    assert mem.alias_size_in_bytes >= 0.99 * state_bytes
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < 16e9 and used < V5E_PROGRAM_BYTES, used

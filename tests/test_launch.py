@@ -121,7 +121,7 @@ def test_shape_applicability_matrix():
                 n_skip += 1
                 assert shape.name == "long_500k"
                 assert "full-attention" in reason
-    assert n_skip == 8  # exactly the 8 structurally-skipped cells
+    assert n_skip == 9  # exactly the 9 structurally-skipped cells
 
 
 def test_variant_config_composition():

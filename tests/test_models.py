@@ -72,10 +72,12 @@ def test_arch_smoke_decode_step(name):
 
 @pytest.mark.parametrize("name", ["stablelm-3b", "qwen3-4b", "xlstm-1.3b",
                                   "recurrentgemma-2b",
-                                  "granite-moe-1b-a400m", "musicgen-medium"])
+                                  "granite-moe-1b-a400m", "musicgen-medium",
+                                  "qwen3-next-80b-a3b"])
 def test_decode_matches_forward(name):
     """Step-by-step decode must reproduce teacher-forced logits (validates
-    KV ring buffers, mLSTM chunkwise algebra, RG-LRU scan, MoE decode)."""
+    KV ring buffers, mLSTM chunkwise algebra, RG-LRU scan, MoE decode,
+    the Gated DeltaNet chunked form)."""
     cfg, params = _setup(name, prefix_len=0, compute_dtype="float32")
     b, s = 2, 20
     toks = jax.random.randint(jax.random.key(1), (b, s), 0, cfg.vocab_size)
