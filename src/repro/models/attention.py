@@ -275,73 +275,134 @@ def attention(params, cfg, x, sin, cos, *, window: int = 0):
     return shard_as(out, "batch", "seq", "embed_act")
 
 
+# the most of one layer's past K (or V) that decode reads in one pass: a
+# pass that size is staged through the core's own memory (128 MiB on a
+# TPU v5e) instead of copied out in HBM and read again
+PAST_PASS_BYTES = 64 * 2**20
+
+
+def lanes_per_pass(lanes: int, lane_bytes: int) -> int:
+    """The most lanes, a divisor of `lanes`, whose past K fits
+    `PAST_PASS_BYTES` (at least one)."""
+    n = max(1, min(lanes, PAST_PASS_BYTES // lane_bytes))
+    while lanes % n:
+        n -= 1
+    return n
+
+
+def _attend_decode(qg, past, new, valid, scale):
+    """One token's attention over its lanes' valid past slots and itself.
+
+    qg (b, kvh, g, hd); past: the lanes' slots of this layer as the cache
+    holds them (k, v: (b, S, kvh, hd), int8 ones with k_scale, v_scale:
+    (b, S, kvh)); new: the token as the cache holds it, without S;
+    valid (b, S).  Returns the context (b, kvh, g, hd), float32."""
+    quant = "k_scale" in new
+    if quant:
+        # contract against int8 values; fold the per-token scale into the
+        # scores/probs afterwards (keeps HBM reads at 1 byte/elem)
+        qf = qg.astype(jnp.float32)
+        sc = jnp.einsum("bkgd,btkd->bkgt", qf, past["k"].astype(jnp.float32))
+        sc = sc * past["k_scale"].transpose(0, 2, 1)[:, :, None, :] * scale
+        sc_new = jnp.einsum("bkgd,bkd->bkg", qf, new["k"].astype(jnp.float32))
+        sc_new = sc_new * new["k_scale"][:, :, None] * scale
+    else:
+        sc = jnp.einsum("bkgd,btkd->bkgt", qg, past["k"].astype(qg.dtype)
+                        ).astype(jnp.float32) * scale
+        sc_new = jnp.einsum("bkgd,bkd->bkg", qg, new["k"].astype(qg.dtype)
+                            ).astype(jnp.float32) * scale
+    sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
+    # softmax over the past slots and the new token
+    top = jnp.maximum(sc.max(axis=-1), sc_new)
+    e = jnp.exp(sc - top[..., None])
+    e_new = jnp.exp(sc_new - top)
+    total = e.sum(axis=-1) + e_new
+    probs = e / total[..., None]
+    p_new = (e_new / total)[..., None]
+    if quant:
+        pw = probs * past["v_scale"].transpose(0, 2, 1)[:, :, None, :]
+        ctx = jnp.einsum("bkgt,btkd->bkgd", pw, past["v"].astype(jnp.float32))
+        v_new = new["v_scale"][..., None] * new["v"].astype(jnp.float32)
+    else:
+        ctx = jnp.einsum("bkgt,btkd->bkgd", probs.astype(qg.dtype),
+                         past["v"].astype(qg.dtype),
+                         preferred_element_type=jnp.float32)
+        v_new = new["v"].astype(jnp.float32)
+    return ctx + p_new * v_new[:, :, None, :]
+
+
 def attention_decode(params, cfg, x, sin, cos, cache,
-                     *, window: int = 0):
+                     *, window: int = 0, layer=None):
     """One-token decode.  x: (b, 1, d); cache holds past KV (bf16 KVCache
-    or int8 KVCacheQ)."""
+    or int8 KVCacheQ).
+
+    With ``layer``, ``cache`` is a stack of layers' caches (a leading
+    layer axis) and this is layer ``layer``: only the new token's K and V
+    are written into the stack, in place, and the stack comes back.  The
+    layer's past slots are read from the stack before the write, a pass
+    of `lanes_per_pass` lanes at a time, and the new token is scored
+    apart, so no copy of the layer is written back."""
+    if layer is None:
+        out, cache = attention_decode(params, cfg, x, sin, cos,
+                                      jax.tree.map(lambda a: a[None], cache),
+                                      window=window, layer=0)
+        return out, jax.tree.map(lambda a: a[0], cache)
     b, s, _ = x.shape
     assert s == 1
     hd = cfg.resolved_head_dim
     q, k, v, gate = _project_qkv(params, cfg, x, sin, cos)
-    size = cache.k.shape[1]
-    ring = window > 0
+    size = cache.k.shape[2]
+    pos = cache.pos[layer]                                        # (b,)
     # per-lane positions: each batch lane writes at its own slot (true
     # continuous batching — lanes restart independently, see serve.engine)
-    lanes = jnp.arange(b)
-    slot = jax.lax.rem(cache.pos, size) if ring else cache.pos  # (b,)
-    quant = isinstance(cache, KVCacheQ)
-    if quant:
+    slot = jax.lax.rem(pos, size) if window > 0 else pos         # (b,)
+    # the new token as the cache holds it
+    if isinstance(cache, KVCacheQ):
         kq, ks = _quantize_token(k)
         vq, vs = _quantize_token(v)
-        new_k = cache.k.at[lanes, slot].set(kq[:, 0])
-        new_v = cache.v.at[lanes, slot].set(vq[:, 0])
-        new_ks = cache.k_scale.at[lanes, slot].set(ks[:, 0])
-        new_vs = cache.v_scale.at[lanes, slot].set(vs[:, 0])
+        new = dict(k=kq[:, 0], v=vq[:, 0], k_scale=ks[:, 0], v_scale=vs[:, 0])
     else:
-        new_k = cache.k.at[lanes, slot].set(k[:, 0].astype(cache.k.dtype))
-        new_v = cache.v.at[lanes, slot].set(v[:, 0].astype(cache.v.dtype))
+        new = dict(k=k[:, 0].astype(cache.k.dtype),
+                   v=v[:, 0].astype(cache.v.dtype))
+    # the past slots that hold an earlier position of each lane; the slot
+    # being written holds none (ring: slot t holds position pos - age)
+    t = jnp.arange(size)
+    if window > 0:
+        age = jax.lax.rem(slot[:, None] - t[None, :] + size, size)  # (b,S)
+        valid = (age > 0) & (age <= jnp.minimum(pos, size - 1)[:, None])
+    else:
+        valid = t[None, :] < pos[:, None]                           # (b,S)
     h = cfg.num_heads
     kvh = cfg.num_kv_heads
-    g = h // kvh
     # decode keeps KV un-repeated (grouped einsum): the cache is the
     # memory-bound object — broadcasting it g-fold would multiply HBM
     # traffic; the cache seq dim is sharded on the model axis instead
     # (rule 'seq_cache'), with GSPMD inserting the tiny softmax-stat
     # collectives.
-    qg = q.reshape(b, kvh, g, hd)
+    qg = q.reshape(b, kvh, h // kvh, hd)
     scale = 1.0 / math.sqrt(hd)
-    if quant:
-        # contract against int8 values; fold the per-token scale into the
-        # scores/probs afterwards (keeps HBM reads at 1 byte/elem)
-        sc = jnp.einsum("bkgd,btkd->bkgt", qg.astype(jnp.float32),
-                        new_k.astype(jnp.float32))
-        sc = sc * new_ks.transpose(0, 2, 1)[:, :, None, :] * scale
-    else:
-        kf = new_k.astype(q.dtype)
-        vf = new_v.astype(q.dtype)
-        sc = jnp.einsum("bkgd,btkd->bkgt", qg, kf).astype(jnp.float32) * scale
-    # validity per lane: slot t holds absolute position
-    # (ring: pos - ((slot-t) mod S))
-    t = jnp.arange(size)
-    if ring:
-        age = jax.lax.rem(slot[:, None] - t[None, :] + size, size)  # (b,S)
-        valid = age <= jnp.minimum(cache.pos, size - 1)[:, None]
-        if window > 0:
-            valid &= age < window
-    else:
-        valid = t[None, :] <= cache.pos[:, None]                    # (b,S)
-    sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
-    probs = jax.nn.softmax(sc, axis=-1)
-    if quant:
-        pw = probs * new_vs.transpose(0, 2, 1)[:, :, None, :]
-        ctx = jnp.einsum("bkgt,btkd->bkgd", pw.astype(jnp.float32),
-                         new_v.astype(jnp.float32)).astype(q.dtype)
-    else:
-        ctx = jnp.einsum("bkgt,btkd->bkgd", probs.astype(q.dtype), vf)
-    ctx = _gated(ctx.reshape(b, 1, h * hd), gate)
+    n = lanes_per_pass(b, math.prod(cache.k.shape[2:])
+                       * cache.k.dtype.itemsize)
+
+    parts = []
+    for lo in range(0, b, n):
+        past = {f: jax.lax.dynamic_slice(a, (layer, lo) + (0,) * (a.ndim - 2),
+                                         (1, n) + a.shape[2:])[0]
+                for f in new for a in [getattr(cache, f)]}
+        ctx = _attend_decode(qg[lo:lo + n], past,
+                             {f: a[lo:lo + n] for f, a in new.items()},
+                             valid[lo:lo + n], scale)
+        # the next pass, and the writes below, wait for this pass: XLA
+        # then reads one pass at a time into the core's memory, instead
+        # of copying the layer out in HBM to keep it past the write
+        ctx, cache = jax.lax.optimization_barrier((ctx, cache))
+        parts.append(ctx)
+    ctx = jnp.concatenate(parts)
+    ctx = _gated(ctx.astype(q.dtype).reshape(b, 1, h * hd), gate)
     out = ctx @ params["wo"].astype(x.dtype)
     out = shard_as(out, "batch", "seq", "embed_act")
-    if quant:
-        return out, KVCacheQ(k=new_k, v=new_v, k_scale=new_ks,
-                             v_scale=new_vs, pos=cache.pos + 1)
-    return out, KVCache(k=new_k, v=new_v, pos=cache.pos + 1)
+    lanes = jnp.arange(b)
+    written = {f: getattr(cache, f).at[layer, lanes, slot].set(a)
+               for f, a in new.items()}
+    return out, cache._replace(pos=cache.pos.at[layer].set(pos + 1),
+                               **written)

@@ -8,7 +8,8 @@ the 512-device dry-run); remainder layers are unrolled.  Remat policy is
 configurable per config ('none' | 'dots' | 'full').
 
 Decode: per-layer caches (KV ring buffers / recurrent states) are stacked
-per pattern position and scanned the same way.
+per pattern position and carried through the same scan; attention writes
+each token's K and V into its stack in place.
 """
 
 from __future__ import annotations
@@ -161,13 +162,15 @@ def block_apply(params, cfg, kind: str, x, sin, cos):
     return x, aux
 
 
-def block_decode(params, cfg, kind: str, x, sin, cos, cache):
+def block_decode(params, cfg, kind: str, x, sin, cos, cache, layer=None):
+    """One-token decode block: returns (x, cache).  ``layer`` (attention
+    only) makes ``cache`` a stack of layers' caches, see attention_decode."""
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     window = cfg.window if kind == "local_attn" else 0
     with _mixer_scope(cfg, kind):
         if kind in ("attn", "local_attn"):
             mix, cache = attention_decode(params["mixer"], cfg, h, sin, cos,
-                                          cache, window=window)
+                                          cache, window=window, layer=layer)
         elif kind == "mlstm":
             mix, cache = mlstm_decode(params["mixer"], cfg, h, cache)
         elif kind == "slstm":
@@ -494,25 +497,31 @@ def decode_step(params, cfg, state: DecodeState, tokens):
 
     if g > 0:
         # caches ride in the scan CARRY (not xs/ys): the in-loop
-        # dynamic-update-slice into the carried buffer is aliasable
-        # in-place by XLA, avoiding a second cache-sized buffer — the
-        # xs/ys formulation double-buffers the (large) KV caches.
+        # updates of the carried buffers are aliasable in-place by XLA,
+        # avoiding a second cache-sized buffer — the xs/ys formulation
+        # double-buffers the (large) KV caches.
         def group_body(carry, inp):
             x, caches = carry
             gi, grp_params = inp
-            new_caches = caches
             for pi, kind in enumerate(pattern):
-                cache_g = jax.tree.map(
-                    lambda c: jax.lax.dynamic_index_in_dim(
-                        c, gi, axis=0, keepdims=False), caches[pi])
-                x, c2 = block_decode(grp_params[pi], cfg, kind, x, sin, cos,
-                                     cache_g)
-                upd = jax.tree.map(
-                    lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                        full, new.astype(full.dtype), gi, axis=0),
-                    new_caches[pi], c2)
-                new_caches = new_caches[:pi] + (upd,) + new_caches[pi + 1:]
-            return (x, new_caches), None
+                if kind in ("attn", "local_attn"):
+                    # writes its one new position into the stack
+                    x, upd = block_decode(grp_params[pi], cfg, kind, x, sin,
+                                          cos, caches[pi], layer=gi)
+                else:
+                    # a recurrent state is rewritten whole every step:
+                    # the layer's is taken out of the stack and put back
+                    cache_g = jax.tree.map(
+                        lambda c: jax.lax.dynamic_index_in_dim(
+                            c, gi, axis=0, keepdims=False), caches[pi])
+                    x, c2 = block_decode(grp_params[pi], cfg, kind, x, sin,
+                                         cos, cache_g)
+                    upd = jax.tree.map(
+                        lambda full, new: jax.lax.dynamic_update_index_in_dim(
+                            full, new.astype(full.dtype), gi, axis=0),
+                        caches[pi], c2)
+                caches = caches[:pi] + (upd,) + caches[pi + 1:]
+            return (x, caches), None
 
         (x, new_group_caches), _ = jax.lax.scan(
             group_body, (x, state.group_caches),

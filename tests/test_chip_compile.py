@@ -87,6 +87,29 @@ def test_grouped_matmul_compiles(one_chip, e, d, f):
     assert _is_kernel(compiled)
 
 
+def _top_level(text: str) -> list[str]:
+    """The instructions of the entry and the loop bodies: of every
+    computation that no fusion calls."""
+    fused = set(re.findall(r"fusion\(.*?calls=(%[\w.-]+)", text))
+    top, in_fusion = [], False
+    for line in text.splitlines():
+        if re.match(r"^\S.*\{$", line):
+            words = line.split()
+            in_fusion = words[words[0] == "ENTRY"] in fused
+        elif not in_fusion and re.match(r"\s+(ROOT )?%\S+ = ", line):
+            top.append(line.strip())
+    return top
+
+
+def _results(line: str) -> tuple[str, list[tuple[str, str]]]:
+    """An instruction's op and its results as (type, layout), e.g.
+    ("bf16[36,16,2048,8,128]", "4,3,2,1,0:T(8,128)(2,1)")."""
+    rhs = line.split(" = ", 1)[1]
+    end = rhs.index(") ") + 1 if rhs.startswith("(") else rhs.index(" ")
+    op = rhs[end + 1:].split("(", 1)[0]
+    return op, re.findall(r"(\w+\[[\d,]*\])\{([^}]*)\}", rhs[:end])
+
+
 def test_qwen3_4b_decode_step_fits_one_chip(one_chip):
     """bf16 weights, 8 lanes x max_len 2048, state donated: the program
     fits, and the donated state is aliased instead of double-buffered."""
@@ -139,21 +162,10 @@ def test_qwen3_moe_decode_step_reads_experts_in_place(one_chip):
     compiled = decode_program(cfg).lower(params, state, tokens).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
 
-    # instructions of the entry and the loop bodies: every computation
-    # that no fusion calls
-    text = compiled.as_text()
-    fused = set(re.findall(r"fusion\(.*?calls=(%[\w.-]+)", text))
-    top, in_fusion = [], False
-    for line in text.splitlines():
-        if re.match(r"^\S.*\{$", line):
-            words = line.split()
-            in_fusion = words[words[0] == "ENTRY"] in fused
-        elif not in_fusion:
-            top.append(line.strip())
     expert_stacks = ("bf16[1,128,2048,768]", "bf16[1,128,768,2048]",
                      "bf16[128,2048,768]", "bf16[128,768,2048]")
-    copies = [ln for ln in top if " = " in ln and ln.split(" = ", 1)[
-        1].startswith(expert_stacks)]
+    copies = [ln for ln in _top_level(compiled.as_text())
+              if ln.split(" = ", 1)[1].startswith(expert_stacks)]
     assert not copies, copies
 
 
@@ -199,13 +211,14 @@ def _hlo_digest(compiled) -> str:
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-# computed on the tree before the Qwen3-Next block kinds, gate, partial
-# rotary and expert share were added (same JAX and TPU compiler)
+# the decode programs as they write each token's K and V into the
+# stacked cache in place (same JAX and TPU compiler): a later change that
+# alters them has to re-pin them here, on purpose
 DECODE_HLO = {
     ("qwen3-4b", 36, 8):
-        "d8dd43a2ee1defd4aa513dd1a51d10e0af82725bbafc69144e20b706a08327c5",
+        "6ef8784ce1ec7a72552b3f2c7fb6f440c90a0eea57413042b31a02aacf1645b7",
     ("qwen3-moe-30b-a3b", 8, 32):
-        "375a9f2b743baa5f7a154bb6f3174248fbb6cf13789bb58084a21e291ca79a13",
+        "9ce086d34a965cc8da2a216e5b0572b0546b6d695ef34fd4799fa4967e3ba2ac",
 }
 
 
@@ -213,13 +226,65 @@ DECODE_HLO = {
 def test_existing_decode_steps_compile_to_the_same_hlo(one_chip, arch, layers,
                                                        lanes):
     """The benchmark's qwen3-4b and qwen3-moe-30b-a3b-8l decode steps
-    (max_len 2048) compile as before: Qwen3-Next's additions are off by
-    default and leave these programs as they were."""
+    (max_len 2048) compile to the programs pinned above, so a change meant
+    for another model or path leaves them as they are."""
     from repro.configs import get_arch
 
     cfg = dataclasses.replace(get_arch(arch), num_layers=layers)
     compiled, _ = _decode_step(cfg, lanes, 2048, one_chip)
     assert _hlo_digest(compiled) == DECODE_HLO[(arch, layers, lanes)]
+
+
+# the benchmark's decode programs: layers, lanes, max_len, experts held
+BENCH_DECODE = {
+    "qwen3-4b": (36, 16, 2048, 0),
+    "qwen3-moe-30b-a3b": (8, 32, 2048, 0),
+    "qwen3-next-80b-a3b": (8, 128, 4096, 128),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(BENCH_DECODE))
+def test_decode_step_writes_one_position_of_the_stacked_cache(one_chip,
+                                                              arch):
+    """Each layer's attention writes the new token's K and V into the
+    stacked cache in place: outside any fusion no instruction writes a
+    layer back into the stack (a dynamic-update-slice) or copies the stack
+    or a layer of it, and the part of a layer read at a time is staged in
+    the core's own memory (S(1)), not copied out in HBM.  The donated state
+    is still aliased, not double-buffered."""
+    from repro.configs import get_arch
+
+    layers, lanes, max_len, held = BENCH_DECODE[arch]
+    cfg = dataclasses.replace(get_arch(arch), num_layers=layers)
+    if held:
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                               held=held))
+    compiled, state = _decode_step(cfg, lanes, max_len, one_chip)
+    state_bytes = sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                      for leaf in jax.tree.leaves(state))
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        0.99 * state_bytes)
+
+    kv = [c for c in state.group_caches if hasattr(c, "k")]
+    assert kv
+    stacks = {f"bf16{list(c.k.shape)}".replace(" ", "") for c in kv}
+    # a layer, or a pass of it: every shape that ends in (S, kvh, hd)
+    tails = {"," + ",".join(map(str, c.k.shape[2:])) + "]" for c in kv}
+    faults, parts = [], 0
+    for line in _top_level(compiled.as_text()):
+        op, results = _results(line)
+        name = line.split(" = ", 1)[0]
+        for typ, layout in results:
+            if not typ.startswith("bf16[") or not typ.endswith(tuple(tails)):
+                continue
+            if op.startswith("copy") or "dynamic-update-slice" in name + op:
+                faults.append(line)
+            elif typ not in stacks:
+                parts += 1
+                if "S(1)" not in layout:
+                    faults.append(line)
+    assert not faults, faults
+    assert parts >= 2 * len(kv), parts   # the K and V read of each kind
 
 
 def test_qwen3_next_longgen_decode_step_fits_one_chip(one_chip):

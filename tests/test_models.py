@@ -94,6 +94,72 @@ def test_decode_matches_forward(name):
     assert rel < 2e-2, rel
 
 
+@pytest.mark.parametrize("name,over,lane_passes", [
+    ("qwen3-4b", {}, False),
+    ("recurrentgemma-2b", {}, False),
+    ("qwen3-4b", {"kv_cache_dtype": "int8"}, False),
+    ("qwen3-next-80b-a3b", {}, False),
+    ("qwen3-next-80b-a3b", {}, True),
+], ids=["qwen3-4b", "recurrentgemma-2b", "qwen3-4b-int8",
+        "qwen3-next-80b-a3b", "qwen3-next-80b-a3b-lane-passes"])
+def test_decode_lanes_at_different_positions_match_forward(
+        monkeypatch, name, over, lane_passes):
+    """Continuous batching: lane 1 is reset to position 0 mid-run through
+    the engine's splice, as admission does, so the two lanes sit at
+    different positions and lane 1's cache still holds its first request.
+    Each lane's step-by-step logits match forward on that lane's own
+    tokens; the run outlasts recurrentgemma's window, so its ring wraps.
+    With lane passes, decode reads the cache one lane per pass, as a
+    cache too large for one pass is read (`lanes_per_pass`)."""
+    from repro.models import attention
+    from repro.serve.engine import _splice_lane
+
+    if lane_passes:
+        monkeypatch.setattr(attention, "PAST_PASS_BYTES", 1)
+    cfg, params = _setup(name, prefix_len=0, compute_dtype="float32", **over)
+    steps, reset, max_len = 44, 6, 48
+    toks = jax.random.randint(jax.random.key(1), (3, steps), 0,
+                              cfg.vocab_size)
+    lane0 = toks[0]
+    first, second = toks[1, :reset], toks[2, :steps - reset]
+    fwd = jax.jit(lambda t: forward(params, cfg, t[None])[0][0])
+    want = jnp.stack([fwd(lane0), jnp.concatenate([fwd(first), fwd(second)])])
+    st = init_decode_state(cfg, 2, max_len=max_len)
+    fresh = init_decode_state(cfg, 1, max_len=max_len)
+    step = jax.jit(lambda st, t: decode_step(params, cfg, st, t))
+    lane1 = jnp.concatenate([first, second])
+    outs = []
+    for i in range(steps):
+        if i == reset:
+            st = _splice_lane(st, fresh, 1)
+        lg, st = step(st, jnp.stack([lane0[i], lane1[i]])[:, None])
+        outs.append(lg[:, 0])
+    assert st.pos.tolist() == [steps, steps - reset]
+    dec = jnp.stack(outs, axis=1)
+    for lane in range(2):
+        rel = float(jnp.max(jnp.abs(dec[lane] - want[lane]))
+                    / (jnp.max(jnp.abs(want[lane])) + 1e-9))
+        assert rel < 2e-2, (lane, rel)
+
+
+@pytest.mark.parametrize("arch,lanes,max_len,expect", [
+    ("qwen3-4b", 16, 2048, 16),
+    ("qwen3-moe-30b-a3b", 32, 2048, 32),
+    ("qwen3-next-80b-a3b", 128, 4096, 16),
+    ("qwen3-next-80b-a3b", 12, 8192, 6),
+])
+def test_decode_lanes_per_pass_rule(arch, lanes, max_len, expect):
+    """The benchmark's caches in bf16: qwen3-4b and the MoE read a layer's
+    past K in one pass; longgen's 128 lanes x 4096 take passes of 16
+    lanes, and a lane count with no divisor that fits takes the largest
+    one that does."""
+    from repro.models.attention import lanes_per_pass
+
+    cfg = ARCHS[arch]
+    lane_bytes = max_len * cfg.num_kv_heads * cfg.resolved_head_dim * 2
+    assert lanes_per_pass(lanes, lane_bytes) == expect
+
+
 @pytest.mark.parametrize("expert_chunk", [None, 2])
 def test_moe_dense_vs_ragged_dispatch(expert_chunk):
     """The two dispatch paths are equivalent when capacity drops nothing,
