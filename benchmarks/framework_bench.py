@@ -81,94 +81,6 @@ def serving() -> list[dict]:
     return rows
 
 
-def serving_plan_cache() -> list[dict]:
-    """Per-decode-step planning overhead on the serving hot path.
-
-    Replays the lane-length stream `DecodeEngine` sees (8 decode lanes,
-    ragged KV lengths advancing per step, a retire/admit event restarting
-    a lane) under three planning policies:
-
-      * ``every_step`` — re-run the Python chunk planner each decode
-        step (the hot-path behaviour the admission gating fixed);
-      * ``admission``  — plan only when a slot retired/was admitted;
-      * ``admission_cached`` — admission gating + the memoized
-        KernelTilePlan cache (`repro.core.jax_sched.plan_tiles_cached`).
-
-    Two request mixes: ``uniform`` (one request class — the batch-
-    inference / eval-harness regime, where lane signatures cycle and the
-    cache hit rate is high) and ``mixed`` (heterogeneous lengths, the
-    cache's worst case).  The headline number is per-decode-step
-    planning time per policy.
-    """
-    import time as _time
-
-    from repro.core.jax_sched import (kernel_plan_cache_clear,
-                                      kernel_plan_cache_stats,
-                                      plan_tiles_cached,
-                                      plan_tiles_for_kernel)
-
-    slots, kv_block, steps = 8, 16, 400
-
-    def stream(mix: str):
-        """(per-step costs, admission flags) for one request mix."""
-        rng = np.random.default_rng(1)
-        if mix == "uniform":
-            life = lambda: 48
-            start = lambda: 32
-        else:
-            life = lambda: int(rng.integers(8, 48))
-            start = lambda: int(rng.integers(8, 64))
-        age = np.array([int(rng.integers(0, 48)) for _ in range(slots)])
-        lens = np.array([start() + a for a in age], np.int64)
-        until = np.array([life() for _ in range(slots)])
-        per_step = []
-        for _ in range(steps):
-            lens += 1
-            age += 1
-            retired = np.flatnonzero(age >= until)
-            for s in retired:
-                lens[s] = start()
-                age[s] = 0
-                until[s] = life()
-            costs = np.maximum(np.ceil(
-                lens.astype(np.float64) / kv_block), 1.0)
-            per_step.append((costs, bool(len(retired))))
-        return per_step
-
-    rows = []
-    for mix in ("uniform", "mixed"):
-        per_step = stream(mix)
-        n_adm = sum(adm for _, adm in per_step)
-        for policy in ("every_step", "admission", "admission_cached"):
-            cached = policy == "admission_cached"
-            planner = plan_tiles_cached if cached else plan_tiles_for_kernel
-            kernel_plan_cache_clear()
-            t0 = _time.perf_counter()
-            for costs, adm in per_step:
-                if policy == "every_step" or adm:
-                    planner(costs, p=8, technique="fac2")
-            dt = _time.perf_counter() - t0
-            hits = kernel_plan_cache_stats()["hits"]
-            rows.append(dict(
-                name=f"serving_plan_cache/{mix}/{policy}",
-                us_per_call=dt * 1e6 / steps,  # per decode step
-                decode_steps=steps,
-                admissions=n_adm,
-                cache_hits=hits,
-                hit_rate=round(hits / max(n_adm, 1), 3) if cached else 0.0,
-                plan_time_total_ms=round(dt * 1e3, 3)))
-        base, gated, memo = rows[-3:]
-        rows.append(dict(
-            name=f"serving_plan_cache/{mix}/reduction",
-            us_per_call=0.0,
-            vs_every_step=round(base["us_per_call"]
-                                / max(memo["us_per_call"], 1e-9), 1),
-            vs_admission_uncached=round(gated["us_per_call"]
-                                        / max(memo["us_per_call"], 1e-9),
-                                        2)))
-    return rows
-
-
 def kernels() -> list[dict]:
     """Kernel microbenches (interpret mode: correctness-path timing only;
     the BlockSpec geometry is the TPU artifact)."""

@@ -72,7 +72,6 @@ def main() -> None:
         "moe_balance": framework_bench.moe_balance,
         "auto_select": framework_bench.auto_select,
         "serving": framework_bench.serving,
-        "serving_plan_cache": framework_bench.serving_plan_cache,
         "kernels": framework_bench.kernels,
         "packing": framework_bench.packing,
         # *_quick names: emit() writes results/<name>.json, so the

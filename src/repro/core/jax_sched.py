@@ -55,9 +55,6 @@ __all__ = [
     "balanced_assignment",
     "KernelTilePlan",
     "plan_tiles_for_kernel",
-    "plan_tiles_cached",
-    "kernel_plan_cache_stats",
-    "kernel_plan_cache_clear",
 ]
 
 
@@ -633,95 +630,3 @@ def plan_tiles_for_kernel(
         step_cost=costs[order], worker_cost=loads,
         n_chunks=plan.n_chunks, sched_time=o_cs * plan.n_chunks)
 
-
-# ---------------------------------------------------------------------------
-# Zero-overhead serving plan cache — memoized KernelTilePlan lookups
-# ---------------------------------------------------------------------------
-
-#: (cost-signature, p, spec, assign, overhead, weights-bucket) -> plan
-_PLAN_CACHE: "dict[tuple, KernelTilePlan]" = {}
-_PLAN_CACHE_MAX = 1024
-_PLAN_CACHE_STATS = {"hits": 0, "misses": 0, "bypass": 0}
-
-
-def _weights_key(weights, p: int, bucket: float):
-    """Quantize weights into relative buckets so near-identical AWF
-    weight vectors (the common serving steady state: weights drift by
-    <1% between admissions) share one cached plan."""
-    if weights is None:
-        return None
-    w = np.asarray(weights, dtype=np.float64)
-    scale = w.sum() / max(p, 1)
-    if not np.isfinite(scale) or scale <= 0:
-        return ("raw", w.tobytes())
-    q = np.round(w / scale / max(bucket, 1e-9)).astype(np.int64)
-    return (float(bucket), q.tobytes())
-
-
-def plan_tiles_cached(
-    costs: Sequence[float],
-    p: int = 8,
-    technique: Union[ScheduleSpec, str, None] = "fac2",
-    *,
-    weights: Optional[Sequence[float]] = None,
-    assign: str = "greedy",
-    overhead_per_chunk: float = 0.0,
-    cost_fn: Optional[Callable[[np.ndarray], np.ndarray]] = None,
-    weights_bucket: float = 0.05,
-) -> KernelTilePlan:
-    """Memoized :func:`plan_tiles_for_kernel` — the serving hot path.
-
-    ``DecodeEngine.step`` / the cluster router re-plan their decode-KV
-    tile order on every admission, but the (lane-lengths, p, spec)
-    signature repeats constantly under continuous batching: lanes cycle
-    through the same length patterns, and AWF weights move by fractions
-    of a percent between refills.  This front-end keys the plan on
-
-      (cost signature, p, resolved spec, assign, overhead_per_chunk,
-       weights bucket)
-
-    where the weights bucket quantizes normalized weights to multiples
-    of ``weights_bucket`` (5% by default) — weight vectors inside one
-    bucket share a plan, so steady-state serving pays a dict lookup
-    instead of the full Python chunk planner.  A ``cost_fn`` is opaque
-    (unhashable semantics), so those calls bypass the cache.
-
-    Returns a *shared* :class:`KernelTilePlan` — treat its arrays as
-    read-only (``to_record()`` already copies what it mutates).  The
-    cache holds at most 1024 plans (evicting oldest-inserted) and is
-    observable via :func:`kernel_plan_cache_stats` / resettable via
-    :func:`kernel_plan_cache_clear`.
-    """
-    if cost_fn is not None:
-        _PLAN_CACHE_STATS["bypass"] += 1
-        return plan_tiles_for_kernel(
-            costs, p=p, technique=technique, weights=weights,
-            assign=assign, overhead_per_chunk=overhead_per_chunk,
-            cost_fn=cost_fn)
-    spec = resolve(technique, default="fac2")
-    c = np.asarray(costs, dtype=np.float64)
-    key = (c.tobytes(), c.shape, p, spec, assign,
-           float(overhead_per_chunk),
-           _weights_key(weights, p, weights_bucket))
-    plan = _PLAN_CACHE.get(key)
-    if plan is not None:
-        _PLAN_CACHE_STATS["hits"] += 1
-        return plan
-    _PLAN_CACHE_STATS["misses"] += 1
-    plan = plan_tiles_for_kernel(
-        c, p=p, technique=spec, weights=weights, assign=assign,
-        overhead_per_chunk=overhead_per_chunk)
-    if len(_PLAN_CACHE) >= _PLAN_CACHE_MAX:
-        _PLAN_CACHE.pop(next(iter(_PLAN_CACHE)))
-    _PLAN_CACHE[key] = plan
-    return plan
-
-
-def kernel_plan_cache_stats() -> dict:
-    """Copy of the plan-cache counters (hits/misses/bypass + size)."""
-    return dict(_PLAN_CACHE_STATS, size=len(_PLAN_CACHE))
-
-
-def kernel_plan_cache_clear() -> None:
-    _PLAN_CACHE.clear()
-    _PLAN_CACHE_STATS.update(hits=0, misses=0, bypass=0)

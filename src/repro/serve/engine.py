@@ -1,16 +1,17 @@
-"""DecodeEngine: real continuous-batching decode on top of the model.
+"""DecodeEngine: continuous-batching decode on one device.
 
 Binds the DLS RequestScheduler to `models.decode_step`: a fixed pool of
-`slots` decodes in lockstep (one jit'd batched step); when a slot's
-request finishes, the engine pulls a DLS-sized chunk of queued requests
-(FAC2 by default) and refills free slots.  Recurrent/KV state for a
-freed slot is reset by re-prefilling the new request's prompt through
-the same step function (token-by-token prefill keeps the engine simple;
-a production engine fuses a batched prefill — the serving benchmark's
-latency model accounts for it).
+`slots` lanes decodes in lockstep, one jitted batched step per call of
+the step program, with the caches donated and updated in place.  When a
+lane's request finishes, the engine pulls a DLS-sized chunk of queued
+requests (FAC2 by default) for it and reports the finished chunk's
+decode steps back to the scheduler, so adaptive techniques see each
+lane's service time.  A reused lane's KV positions and recurrent state
+are reset, and the new request's prompt is fed through the same step
+program one token per step before its output is sampled.
 
-This is the laptop-scale version of the pod-level engine: slots map to
-batch lanes here, to replicas in the scheduler simulation.
+`launch.serve` builds one engine per replica, and the benchmark drives
+one engine per cell; `spans` records every phase of every step.
 """
 
 from __future__ import annotations
@@ -23,9 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.jax_sched import plan_tiles_cached
-from ..core.metrics import LoopRecorder
-from ..core.schedule import resolve
 from .. import models
 from .scheduler import Request, RequestScheduler
 from .spans import Recorder
@@ -71,9 +69,6 @@ class EngineStats:
     steps: int = 0
     tokens: int = 0
     wall_s: float = 0.0          # the run() call's ``engine.run`` span
-    # requests shed at admission by the deadline-aware policy
-    # (DecodeEngine(shed_slo=...)); 0 when shedding is disabled
-    shed: int = 0
 
     @property
     def tok_per_s(self) -> float:
@@ -84,8 +79,6 @@ class DecodeEngine:
     def __init__(self, cfg, params, slots: int = 4, max_len: int = 128,
                  technique="fac2", greedy: bool = True,
                  temperature: float = 1.0, seed: int = 0,
-                 kernel_schedule="fac2", kernel_p: int = 8,
-                 kv_block: int = 16, shed_slo: Optional[float] = None,
                  device: Optional[jax.Device] = None):
         self.cfg = cfg
         # params, decode state and per-step tokens all live on one device
@@ -94,22 +87,7 @@ class DecodeEngine:
         self.params = jax.device_put(params, self.device)
         self.slots = slots
         self.max_len = max_len
-        # deadline-aware shedding (serve/resilience.py's admission
-        # policy at the engine level): with a step budget of
-        # shed_slo * healthy_lanes, backlog beyond what healthy capacity
-        # can decode inside the budget is shed at refill instead of
-        # queueing unbounded; None disables (byte-identical behavior)
-        self.shed_slo = shed_slo
-        self.shed_rids: list[int] = []
         self.sched = RequestScheduler(num_workers=slots, technique=technique)
-        # decode-attention KV tile planning: the same
-        # plan_tiles_for_kernel path the Pallas kernels use, driven by the
-        # ragged per-lane cache lengths; records land in kernel_recorder
-        # (LoopInstanceRecord telemetry an AutoSelector can consume)
-        self.kernel_spec = resolve(kernel_schedule, default="fac2")
-        self.kernel_p = kernel_p
-        self.kv_block = kv_block
-        self.kernel_recorder = LoopRecorder()
         self._step = decode_program(cfg)
         with jax.default_device(self.device):
             self.state = _init_state(cfg=cfg, batch=slots, max_len=max_len)
@@ -132,16 +110,15 @@ class DecodeEngine:
         # techniques (AF/AWF*) see real per-slot service times
         self._chunk_steps = [0] * slots
         self._chunk_open = [False] * slots
-        # serving plan cache bookkeeping: plans are (re)computed only on
-        # admission change, through the memoized KernelTilePlan cache;
         # the live-lane mask is maintained incrementally so the hot loop
-        # never rebuilds Python lists per decode step
+        # never rebuilds Python lists per decode step; a refill runs only
+        # after a lane retired (or a lane fault changed the pool)
         self._active_mask = np.zeros(slots, bool)
         self._disabled = [False] * slots  # lanes out of service (faults)
         self._need_refill = True
         # the operator's record of the serving loop (serve/spans.py): a
-        # span per phase of each step, one per request from submit to its
-        # lane, and the plan-cache hits under counts["plan_cache_hits"]
+        # span per phase of each step and one per request from submit to
+        # its lane
         self.spans = Recorder()
         self._submitted: dict[int, float] = {}   # rid -> submit time
         self._steps_run = 0      # decode steps so far: the step spans' ident
@@ -190,13 +167,12 @@ class DecodeEngine:
         self._queue[s] = []
         self._chunk_open[s] = False
         self._chunk_steps[s] = 0
-        self.sched._outstanding.pop(s, None)  # drop the open grant too
+        self.sched.release(s)  # drop the open grant too
         self._need_refill = True
 
     def run(self, max_steps: int = 10_000) -> EngineStats:
         stats = EngineStats()
         with self.spans.span("engine.run", self._steps_run) as call:
-            self._shed(stats)
             self._refill()
             while self._active_mask.any() or self.sched.backlog:
                 if stats.steps >= max_steps:
@@ -206,8 +182,7 @@ class DecodeEngine:
                 self._advance(stats)
                 if self._need_refill:
                     # only when a slot retired: steady-state decode steps
-                    # skip the admission scan (and any re-planning) entirely
-                    self._shed(stats)
+                    # skip the admission scan entirely
                     self._refill()
         stats.wall_s = call.t1 - call.t0
         return stats
@@ -221,84 +196,10 @@ class DecodeEngine:
         row ``i`` of ``last_logits`` belongs to lane ``i``."""
         return [None if r is None else r.rid for r in self._active]
 
-    @property
-    def kernel_records(self):
-        """Kernel-level telemetry: one LoopInstanceRecord per admission
-        (decode-attention KV tile plan over the ragged lane lengths)."""
-        return self.kernel_recorder.records
-
     # -- internals ---------------------------------------------------------------
-    def _record_kernel_plan(self) -> None:
-        """Plan the decode-attention KV scan as kernel tiles.
-
-        Each active lane's valid KV prefix is ragged (lanes restart
-        independently under continuous batching); the per-lane cost is
-        its live KV block count, and the DLS plan models splitting the
-        attention grid across ``kernel_p`` cores — the same path
-        ``flash_attention(schedule=..., kv_lens=...)`` executes.
-
-        Runs only on admission change (``_refill`` with a pull) and goes
-        through the memoized plan cache: continuous batching revisits the
-        same lane-length signatures constantly, so the steady state pays
-        a dict lookup instead of the Python chunk planner.
-        """
-        live = np.asarray(self.state.pos)[self._active_mask].astype(
-            np.float64)
-        if live.size == 0:
-            return
-        costs = np.maximum(np.ceil(live / self.kv_block), 1.0)
-        from ..core.jax_sched import kernel_plan_cache_stats
-        hits0 = kernel_plan_cache_stats()["hits"]
-        plan = plan_tiles_cached(costs, p=self.kernel_p,
-                                 technique=self.kernel_spec)
-        self.spans.counts["plan_cache_hits"] += \
-            kernel_plan_cache_stats()["hits"] - hits0
-        self.kernel_recorder.add(plan.to_record(
-            "decode_kv",
-            instance=self.kernel_recorder.next_instance("decode_kv")))
-
-    def _shed(self, stats: Optional[EngineStats] = None) -> int:
-        """Deadline-aware shedding: drop the backlog tail the healthy
-        lanes cannot decode within the ``shed_slo`` step budget.
-
-        The per-request step estimate is prefill (its prompt tokens) +
-        decode (its clamped ``max_new_tokens``); requests are admitted
-        in arrival order until the summed estimate exceeds
-        ``shed_slo x healthy_lanes``, and the rest are shed — a bounded
-        queue under gray failure (disabled lanes shrink the budget), in
-        place of unbounded queueing toward a blown SLO.
-        """
-        if self.shed_slo is None:
-            return 0
-        lanes = 0
-        for s in range(self.slots):
-            if not self._disabled[s]:
-                lanes += 1
-        budget = float(self.shed_slo) * lanes
-        acc = 0.0
-        over: dict[int, bool] = {}
-        for req in self.sched._pending[self.sched._head:]:
-            prompt = getattr(req, "prompt_tokens", None)
-            pre = (len(prompt) if prompt is not None
-                   else min(req.prompt_len, self.max_len // 2))
-            est = pre + min(req.max_new_tokens, self.max_len // 2)
-            acc += float(est)
-            if acc > budget:
-                over[req.rid] = True
-        if not over:
-            return 0
-        dropped = self.sched.drop(lambda r: r.rid in over)
-        for req in dropped:
-            self.shed_rids.append(req.rid)
-            self._submitted.pop(req.rid, None)
-        if stats is not None:
-            stats.shed += len(dropped)
-        return len(dropped)
-
     def _refill(self):
         spans, k = self.spans, self._steps_run
         with spans.span("engine.refill", k):
-            admitted = False
             for s in range(self.slots):
                 if self._disabled[s]:
                     continue
@@ -313,7 +214,6 @@ class DecodeEngine:
                             self._queue[s] = chunk
                             self._chunk_open[s] = True
                             self._chunk_steps[s] = 0
-                            admitted = True
                     if self._queue[s]:
                         req = self._queue[s].pop(0)
                         if self._used[s]:
@@ -333,11 +233,6 @@ class DecodeEngine:
                             spans.record("request.queued", t0, spans.now(),
                                          req.rid)
             self._need_refill = False
-            if admitted:
-                # after activation, so the plan sees the admitted lanes too
-                # (a single-slot engine would otherwise never record)
-                with spans.span("engine.plan", k):
-                    self._record_kernel_plan()
 
     def _advance(self, stats: EngineStats):
         spans, k = self.spans, self._steps_run

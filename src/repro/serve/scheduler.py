@@ -8,15 +8,13 @@ DLS-sized chunk of requests instead of one (SS) or a fixed batch
 how heterogeneous replicas (or replicas degraded by long contexts) get
 less work.
 
-Two layers:
-  * `RequestScheduler` — host-side DLS admission over an arrival queue
-    (any technique from repro.core; default FAC2).
-  * `DecodeEngine` — jit'd batched decode loop over slot states with
-    prefill-on-admit; integrates with models.decode_step.
-
-The engine runs on whatever devices exist (CPU harness here, pod mesh in
-production); the scheduler's simulated-latency mode drives the serving
-benchmark (benchmarks/serving_balance.py).
+`RequestScheduler` is the host-side DLS admission over an arrival queue
+(any technique from repro.core; default FAC2).  `serve.engine.DecodeEngine`
+pulls from it, one worker per decode lane, and reports each chunk's
+decode steps through `complete`; `serve.cluster` keeps one per replica
+and one over the replicas.
+`simulate_serving` drives the same scheduler with a cost model in place
+of the model, for the simulated serving studies.
 """
 
 from __future__ import annotations
@@ -190,25 +188,16 @@ class RequestScheduler:
             self._head = 0
         return out
 
-    def drop(self, pred) -> list[Request]:
-        """Remove every pending request matching ``pred``; return them.
+    def release(self, worker: int) -> None:
+        """Drop ``worker``'s open grant without a measurement; a no-op
+        when it holds none.
 
-        The admission-shedding hook (``DecodeEngine`` deadline-aware
-        shedding): dropped requests were never granted, so no technique
-        or telemetry state needs repair — the next plan rebuild simply
-        sees the smaller backlog.
+        The lane-fault path: the worker's requests went back to the
+        backlog unfinished, and a partial chunk's service time attributed
+        to a dead lane would corrupt the adaptive weights, so nothing is
+        reported.  The worker's next ``pull`` opens a fresh grant.
         """
-        keep: list[Request] = []
-        dropped: list[Request] = []
-        for req in self._pending[self._head:]:
-            if pred(req):
-                dropped.append(req)
-            else:
-                keep.append(req)
-        if dropped:
-            self._pending = keep
-            self._head = 0
-        return dropped
+        self._outstanding.pop(worker, None)
 
     def neutralize_worker(self, worker: int) -> None:
         """Mark ``worker``'s adaptive state for neutralization at the

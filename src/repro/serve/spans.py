@@ -23,12 +23,11 @@ from jax.profiler import TraceAnnotation
 __all__ = ["ENGINE_SPANS", "RING", "Recorder"]
 
 #: Spans of one ``DecodeEngine.run`` call.  ``engine.run`` holds the
-#: others; ``engine.splice`` and ``engine.plan`` lie inside
-#: ``engine.refill``.
-ENGINE_SPANS = ("engine.run", "engine.refill", "engine.splice", "engine.plan",
+#: others; ``engine.splice`` lies inside ``engine.refill``.
+ENGINE_SPANS = ("engine.run", "engine.refill", "engine.splice",
                 "engine.upload", "engine.dispatch", "engine.sample",
                 "engine.sync", "engine.lanes")
-#: Ring size: 120 s at 30 steps/s holds at most 32,400 step spans (nine a
+#: Ring size: 120 s at 30 steps/s holds at most 28,800 step spans (eight a
 #: step), and a run's requests add one ``request.queued`` each.
 RING = 1 << 16
 

@@ -11,7 +11,7 @@ import pytest
 from repro.configs import ARCHS, smoke_config
 from repro.models import init_decoder
 from repro.serve.engine import DecodeEngine
-from repro.serve.scheduler import Request
+from repro.serve.scheduler import Request, RequestScheduler
 
 
 @pytest.fixture(scope="module")
@@ -27,9 +27,11 @@ def _req(rid, prompt_len=6, new=8):
                    max_new_tokens=new)
 
 
-def test_engine_completes_all_requests(model):
+@pytest.mark.parametrize("technique",
+                         ["fac2", "ss", "static", "tss", "fac", "awf_b", "af"])
+def test_engine_completes_all_requests(model, technique):
     cfg, params = model
-    eng = DecodeEngine(cfg, params, slots=4, max_len=64)
+    eng = DecodeEngine(cfg, params, slots=4, max_len=64, technique=technique)
     for i in range(10):
         eng.submit(_req(i))
     stats = eng.run()
@@ -96,29 +98,30 @@ def test_engine_reports_chunk_service_times(model):
     assert {w for w, _ in completed} <= {0, 1}
 
 
-def test_engine_plans_only_on_admission_change(model):
-    """The serving hot path must not re-plan per decode step: planning
-    happens once per admission (one ``engine.plan`` span per kernel
-    record), repeated lane-length signatures come out of the memo cache,
-    and steady-state decode steps skip the admission scan entirely."""
-    from repro.core.jax_sched import kernel_plan_cache_clear
-
-    kernel_plan_cache_clear()
+def test_engine_refills_only_when_a_lane_retires(model):
+    """The serving hot path must not scan for admissions per decode step:
+    within one ``run()`` call there is one ``engine.refill`` at its start
+    and one after each step in which a lane retired, and no other."""
     cfg, params = model
     eng = DecodeEngine(cfg, params, slots=2, max_len=64)
-    # identical requests -> identical lane-length signatures across
-    # admissions -> the cache serves the repeats
     for i in range(8):
         eng.submit(_req(i, prompt_len=4, new=4))
+    retired_steps = 0
+    done = 0
+    orig = eng._advance
+
+    def spy(stats):
+        nonlocal retired_steps, done
+        orig(stats)
+        retired_steps += stats.completed > done
+        done = stats.completed
+
+    eng._advance = spy
     stats = eng.run()
     assert stats.completed == 8
-    plans = [s for s in eng.spans.ring if s[0] == "engine.plan"]
-    assert len(plans) == len(eng.kernel_records)
-    assert len(plans) < stats.steps     # not every decode step
-    assert eng.spans.counts["plan_cache_hits"] > 0  # signatures reused
-    # telemetry still records one plan per admission, in order
-    assert [r.instance for r in eng.kernel_records] == \
-        list(range(len(eng.kernel_records)))
+    refills = [s for s in eng.spans.ring if s[0] == "engine.refill"]
+    assert len(refills) == 1 + retired_steps
+    assert len(refills) < stats.steps     # not every decode step
 
 
 def test_engine_slot_disable_mid_stream(model):
@@ -181,50 +184,43 @@ def test_engine_disabled_slot_drops_partial_measurement(model):
     assert 1 in reported       # the survivor still reports
 
 
-def test_engine_sheds_backlog_tail_over_slo(model):
-    """With a shed_slo step budget, the backlog tail the lanes cannot
-    decode in time is dropped at admission — bounded queue, recorded
-    rids — and everything admitted still completes."""
-    cfg, params = model
-    eng = DecodeEngine(cfg, params, slots=2, max_len=64, shed_slo=30.0)
-    for i in range(10):
-        eng.submit(_req(i, prompt_len=6, new=8))
-    stats = eng.run()
-    assert stats.shed > 0
-    assert stats.completed + stats.shed == 10
-    assert sorted(eng.shed_rids) == sorted(set(eng.shed_rids))
-    assert len(eng.shed_rids) == stats.shed
-    # arrival order: the *tail* is shed, the head is served
-    assert 0 not in eng.shed_rids
-    for i in range(10):
-        if i not in eng.shed_rids:
-            assert len(eng.output(i)) == 8
+def _tech_state(sched):
+    return {k: np.array(v).tolist() for k, v in sched._tech._state().items()}
 
 
-def test_engine_shedding_disabled_by_default(model):
-    cfg, params = model
-    eng = DecodeEngine(cfg, params, slots=2, max_len=64)
-    for i in range(10):
-        eng.submit(_req(i, new=4))
-    stats = eng.run()
-    assert stats.shed == 0 and eng.shed_rids == []
-    assert stats.completed == 10
+@pytest.mark.parametrize("granted", [True, False],
+                         ids=["after_pull", "no_grant"])
+def test_scheduler_release_drops_the_open_grant(granted):
+    """``release`` drops a worker's open grant unmeasured, so a later
+    ``complete()`` leaves the adaptive state as it was; on a worker with
+    no grant it changes nothing, and other workers' grants stay open."""
+    sched = RequestScheduler(num_workers=2, technique="af")
+    for i in range(64):
+        sched.submit(_req(i))
+    if granted:
+        assert sched.pull(0)
+    assert sched.pull(1)
+    backlog, before = sched.backlog, _tech_state(sched)
+    sched.release(0)
+    sched.complete(0, elapsed=3.0)
+    assert _tech_state(sched) == before
+    assert sched.backlog == backlog
+    sched.complete(1, elapsed=5.0)     # worker 1's grant is still measured
+    assert _tech_state(sched) != before
 
 
-def test_engine_disabled_lane_shrinks_shed_budget(model):
-    """A gray-failed (disabled) lane halves the step budget: the same
-    backlog sheds more."""
-    cfg, params = model
-    shed_counts = []
-    for disable in (False, True):
-        eng = DecodeEngine(cfg, params, slots=2, max_len=64, shed_slo=40.0)
-        if disable:
-            eng.set_slot_enabled(1, False)
-        for i in range(10):
-            eng.submit(_req(i, prompt_len=6, new=8))
-        stats = eng.run()
-        shed_counts.append(stats.shed)
-    assert shed_counts[1] > shed_counts[0]
+def test_scheduler_release_then_pull_opens_a_fresh_grant():
+    """A pull after ``release`` does not fold into the dropped grant: the
+    next measurement is attributed to the new take alone."""
+    sched = RequestScheduler(num_workers=2, technique="af")
+    for i in range(64):
+        sched.submit(_req(i))
+    first = sched.pull(0)
+    sched.release(0)
+    second = sched.pull(0)
+    assert first and second
+    sched.complete(0, elapsed=4.0)
+    assert _tech_state(sched)["_cnt"][0] == len(second)
 
 
 def test_engine_donates_decode_state(model):

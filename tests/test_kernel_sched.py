@@ -7,7 +7,6 @@ Property-tested over specs: the full registry, plus chunk-param variants
 and both chunk->core assignment modes.
 """
 
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -274,125 +273,3 @@ def test_moe_balancer_passes_spec_down_and_records():
     assert len(recs) == 1 and recs[0].loop == "grouped_matmul"
     bal.plan_kernel_tiles(rows, block_rows=8, p=4)
     assert [r.instance for r in bal.kernel_recorder.records] == [0, 1]
-
-
-@pytest.fixture(scope="module")
-def smoke_model():
-    from repro.configs import ARCHS, smoke_config
-    from repro.models import init_decoder
-
-    cfg = dataclasses.replace(smoke_config(ARCHS["qwen3-4b"]),
-                              prefix_len=0, compute_dtype="float32")
-    params, _ = init_decoder(jax.random.key(0), cfg)
-    return cfg, params
-
-
-def test_decode_engine_records_kernel_plans(smoke_model):
-    from repro.serve.engine import DecodeEngine
-    from repro.serve.scheduler import Request
-
-    cfg, params = smoke_model
-    eng = DecodeEngine(cfg, params, slots=2, max_len=32,
-                       kernel_schedule="gss", kernel_p=4, kv_block=4)
-    for i in range(4):
-        eng.submit(Request(rid=i, arrival=0.0, prompt_len=3,
-                           max_new_tokens=4))
-    stats = eng.run()
-    assert stats.completed == 4
-    recs = eng.kernel_records
-    assert recs, "decode must record kernel KV plans"
-    assert all(r.loop == "decode_kv" and r.technique == "gss"
-               for r in recs)
-    assert [r.instance for r in recs] == list(range(len(recs)))
-
-
-def test_decode_engine_single_slot_records_admitted_lane(smoke_model):
-    """The admitted lane must be visible to the plan — a single-slot
-    engine records one KV plan per admission, not zero."""
-    from repro.serve.engine import DecodeEngine
-    from repro.serve.scheduler import Request
-
-    cfg, params = smoke_model
-    eng = DecodeEngine(cfg, params, slots=1, max_len=32, kv_block=4)
-    for i in range(3):
-        eng.submit(Request(rid=i, arrival=0.0, prompt_len=3,
-                           max_new_tokens=4))
-    stats = eng.run()
-    assert stats.completed == 3
-    assert eng.kernel_records, "single-slot engine must record admissions"
-    assert all(r.p == eng.kernel_p for r in eng.kernel_records)
-
-
-# ---------------------------------------------------------------------------
-# plan_tiles_cached — the zero-overhead serving plan cache
-# ---------------------------------------------------------------------------
-
-
-def test_plan_tiles_cached_matches_uncached():
-    from repro.core.jax_sched import (kernel_plan_cache_clear,
-                                      kernel_plan_cache_stats,
-                                      plan_tiles_cached)
-
-    kernel_plan_cache_clear()
-    costs = RNG.integers(1, 40, 24).astype(float)
-    for spec in ("fac2", "gss,2", "awf_b"):
-        cached = plan_tiles_cached(costs, p=4, technique=spec)
-        direct = plan_tiles_for_kernel(costs, p=4, technique=spec)
-        np.testing.assert_array_equal(cached.order, direct.order)
-        np.testing.assert_array_equal(cached.step_worker,
-                                      direct.step_worker)
-        np.testing.assert_allclose(cached.worker_cost, direct.worker_cost)
-    s = kernel_plan_cache_stats()
-    assert s["misses"] == 3 and s["hits"] == 0
-
-
-def test_plan_tiles_cached_hits_on_repeat_signature():
-    from repro.core.jax_sched import (kernel_plan_cache_clear,
-                                      kernel_plan_cache_stats,
-                                      plan_tiles_cached)
-
-    kernel_plan_cache_clear()
-    costs = RNG.integers(1, 40, 16).astype(float)
-    a = plan_tiles_cached(costs, p=4, technique="fac2")
-    b = plan_tiles_cached(costs.copy(), p=4, technique="fac2")
-    assert b is a  # same signature -> shared plan, no re-plan
-    c = plan_tiles_cached(costs, p=8, technique="fac2")
-    assert c is not a  # p is part of the key
-    d = plan_tiles_cached(costs[:-1], p=4, technique="fac2")
-    assert d is not a  # lane lengths changed -> re-plan
-    s = kernel_plan_cache_stats()
-    assert s["hits"] == 1 and s["misses"] == 3
-
-
-def test_plan_tiles_cached_weights_bucket():
-    """Near-identical AWF weight vectors share a plan (same bucket);
-    materially different weights do not."""
-    from repro.core.jax_sched import (kernel_plan_cache_clear,
-                                      plan_tiles_cached)
-
-    kernel_plan_cache_clear()
-    costs = RNG.integers(1, 40, 16).astype(float)
-    w = np.array([1.0, 1.0, 0.5, 1.5])
-    a = plan_tiles_cached(costs, p=4, technique="fac2", weights=w)
-    b = plan_tiles_cached(costs, p=4, technique="fac2",
-                          weights=w * (1.0 + 1e-4))  # sub-bucket drift
-    assert b is a
-    c = plan_tiles_cached(costs, p=4, technique="fac2",
-                          weights=np.array([1.0, 1.0, 1.5, 0.5]))
-    assert c is not a
-
-
-def test_plan_tiles_cached_cost_fn_bypasses():
-    from repro.core.jax_sched import (kernel_plan_cache_clear,
-                                      kernel_plan_cache_stats,
-                                      plan_tiles_cached)
-
-    kernel_plan_cache_clear()
-    costs = RNG.integers(1, 40, 8).astype(float)
-    fn = lambda c: c * 2.0
-    a = plan_tiles_cached(costs, p=4, cost_fn=fn)
-    b = plan_tiles_cached(costs, p=4, cost_fn=fn)
-    assert a is not b  # opaque cost_fn: never memoized
-    assert kernel_plan_cache_stats()["bypass"] == 2
-    direct = plan_tiles_for_kernel(costs, p=4, cost_fn=fn)
-    np.testing.assert_array_equal(a.order, direct.order)

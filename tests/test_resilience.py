@@ -347,17 +347,6 @@ def test_scheduler_take_front():
     assert s.backlog == 0 and s.take_front(1) == []
 
 
-def test_scheduler_drop():
-    s = RequestScheduler(num_workers=2, technique="fac2")
-    for r in _reqs(6):
-        s.submit(r)
-    dropped = s.drop(lambda r: r.rid % 2 == 0)
-    assert sorted(r.rid for r in dropped) == [0, 2, 4]
-    assert s.backlog == 3
-    chunk = s.pull(0)
-    assert all(r.rid % 2 == 1 for r in chunk)
-
-
 def test_cluster_router_take_one():
     router = ClusterRouter(2, schedule="awf_b")
     for r in _reqs(3):

@@ -90,7 +90,21 @@ def test_every_run_call_holds_its_step_spans(model):
     splices = _named(eng.spans, "engine.splice")
     assert splices                          # a lane was reused
     assert {s[3] for s in splices} == {"engine.refill"}
-    assert {s[3] for s in _named(eng.spans, "engine.plan")} == {"engine.refill"}
+    # a refill holds nothing but the splices of the lanes it reuses
+    assert {s[0] for s in ring if s[3] == "engine.refill"} == {"engine.splice"}
+
+
+def test_engine_spans_names_only_spans_a_run_opens(model):
+    """A run that admits, reuses a lane and decodes opens every name in
+    ``ENGINE_SPANS``: the tuple lists no span the engine no longer opens."""
+    cfg, params = model
+    eng = DecodeEngine(cfg, params, slots=1, max_len=32)
+    for i in range(2):
+        eng.submit(_req(i))
+    stats = eng.run()
+    assert stats.completed == 2        # lane 0 was reused for request 1
+    names = {s[0] for s in eng.spans.ring}
+    assert names == set(ENGINE_SPANS) | {"request.queued"}
 
 
 def test_request_queued_runs_from_submit_to_placement(model):
